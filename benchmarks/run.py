@@ -67,15 +67,14 @@ def _summary(name, **headline):
 # ----------------------------------------------------------------------
 
 def bench_kernels(quick: bool):
-    from repro.kernels import ref
-    from repro.kernels.kmeans import kmeans_assign
+    from repro.kernels import ops, ref
     key = jax.random.PRNGKey(0)
     n, f, k = (512, 128, 10) if quick else (4096, 256, 10)
     x = jax.random.normal(key, (n, f))
     c = jax.random.normal(jax.random.fold_in(key, 1), (k, f))
     us_ref = _t(lambda: ref.kmeans_assign_ref(x, c))
-    lab_p = kmeans_assign(x, c)[0]      # interpret probed per backend
-    us_pal = _t(lambda: kmeans_assign(x, c)[0])
+    lab_p = ops.kmeans_assign(x, c, impl="pallas")
+    us_pal = _t(lambda: ops.kmeans_assign(x, c, impl="pallas"))
     match = bool((lab_p == ref.kmeans_assign_ref(x, c)).all())
     _row("kmeans_assign_ref", us_ref, f"N={n} F={f} K={k}")
     _row("kmeans_assign_pallas", us_pal, f"match={match}")
@@ -890,6 +889,7 @@ BENCHES = {
 
 def main() -> None:
     from repro import obs
+    from repro.launch.compile_cache import use_compile_cache
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None,
@@ -898,6 +898,7 @@ def main() -> None:
                     help="capture a jax.profiler trace of the selected "
                          "benchmarks for TensorBoard/Perfetto")
     args = ap.parse_args()
+    use_compile_cache()
     names = args.only.split(",") if args.only else list(BENCHES)
     print("name,us_per_call,derived")
     with obs.maybe_profile(args.profile_dir):

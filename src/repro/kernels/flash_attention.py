@@ -80,7 +80,7 @@ def _pad_axis(x, m, axis):
     "causal", "window", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q, k, v: (B, S, H, hd) with kv already expanded to H heads (GQA is the
     caller's reshape). Returns (B, S, H, hd)."""
     B, Sq, H, hd = q.shape

@@ -14,19 +14,28 @@ TPU mapping (both kernels):
 
   * grid over blocks of N; each step loads an (BN, F) tile of features into
     VMEM (BlockSpec), with the full (K, F) centroid matrix resident (K is
-    small: the paper uses J=10 clusters; padded to the 128-lane MXU width);
-  * distances via the MXU:  ||x-c||^2 = ||x||^2 - 2 x·c^T + ||c||^2 — the
-    x·c^T term is a (BN, F) @ (F, K) matmul, hardware-aligned when BN and K
-    are multiples of (8, 128) and F of 128;
-  * argmin + min-distance computed in-register, written per tile;
-  * (lloyd_step) the tile's one-hot^T @ x partial sums and counts are
-    accumulated into a (K, F) / (1, K) output block that every grid step
+    small: the paper uses J=10 clusters; padded to 128 rows);
+  * distances via the MXU:  ||x-c||^2 = ||x||^2 - 2 c·x^T + ||c||^2 — the
+    c·x^T term is a (Kp, F) @ (F, BN) matmul at f32 precision, so clients
+    sit on lanes and centroids on sublanes;
+  * argmin + min-distance reduce over sublanes into (1, BN) rows, written
+    as lane-dense slices of a (1, Npad) output (a 1-D (BN,) block does not
+    match the TPU's tiling of an (Npad,) array and is refused by Mosaic);
+  * (lloyd_step) the tile's one-hot @ x partial sums and counts are
+    accumulated into a (Kp, F) / (Kp, 1) output block that every grid step
     maps to — zeroed at step 0, so the sequential TPU grid acts as the
     reduction loop.
 
-``interpret=None`` (the default) probes the backend: compiled on TPU,
-interpret mode elsewhere. Validated in interpret mode against
-ref.kmeans_assign_ref / ref.lloyd_step_ref (CPU container).
+VMEM: at the default BN=128 the double-buffered feature tile, resident
+centroids and (lloyd_step) sum accumulator fit v5e's default scoped VMEM
+for F <= 4096 in f32 (tests/test_tpu_compile.py compiles F = 256 and 4096
+for a described v5e); lloyd_step at F = 8192 runs out of VMEM. Stage 1
+projects wider features to ``cluster_feature_dim`` first.
+
+The kernels compile for the TPU by default; ``interpret=True`` runs them
+under the Pallas interpreter (the CPU tests, against
+ref.kmeans_assign_ref / ref.lloyd_step_ref). Platform dispatch lives in
+repro.kernels.ops.
 """
 from __future__ import annotations
 
@@ -37,56 +46,65 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _resolve_interpret(interpret):
-    """Backend probe: compiled Pallas on TPU, interpreter elsewhere."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
-
-
-def _distances(x, c, cn, k_real):
-    """(BN, Kp) squared distances with padded centroid columns = +inf."""
+def _distances(x, c, k_real):
+    """(Kp, BN) squared distances, centroids on sublanes and clients on
+    lanes, padded centroid rows = +inf.  The client axis on lanes is what
+    makes the per-client outputs lane-dense (1, BN) rows."""
+    c = c.astype(jnp.float32)
+    x = x.astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
     prod = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)       # (BN, Kp) on the MXU
-    xn = jnp.sum(x * x, axis=1, keepdims=True)    # (BN, 1)
-    d = xn - 2.0 * prod + cn                      # (BN, Kp)
-    col = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
-    return jnp.where(col < k_real, d, jnp.inf), col
+        c, x, (((1,), (1,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)       # (Kp, BN) on the MXU
+    # ||x||^2 as a (1, BN) row: a ones-row matmul puts the client axis on
+    # lanes without a sublane->lane relayout
+    ones = jnp.ones((8, x.shape[1]), jnp.float32)
+    xn = jax.lax.dot_general(
+        ones, x * x, (((1,), (1,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)[:1]   # (1, BN)
+    cn = jnp.sum(c * c, axis=1, keepdims=True)    # (Kp, 1)
+    d = xn - 2.0 * prod + cn                      # (Kp, BN)
+    row = jax.lax.broadcasted_iota(jnp.int32, d.shape, 0)
+    return jnp.where(row < k_real, d, jnp.inf), row
 
 
-def _assign_kernel(x_ref, c_ref, cn_ref, lab_ref, dist_ref, *, k_real: int):
-    x = x_ref[...].astype(jnp.float32)            # (BN, F)
-    c = c_ref[...].astype(jnp.float32)            # (Kp, F)
-    cn = cn_ref[...]                              # (1, Kp) ||c||^2
-    d, _ = _distances(x, c, cn, k_real)
-    lab_ref[...] = jnp.argmin(d, axis=1).astype(jnp.int32)
-    dist_ref[...] = jnp.min(d, axis=1)
+def _argmin_rows(d, row):
+    """First-index argmin over the centroid (sublane) axis -> (1, BN)."""
+    dmin = jnp.min(d, axis=0, keepdims=True)
+    lab = jnp.min(jnp.where(d == dmin, row, d.shape[0]), axis=0,
+                  keepdims=True)
+    return lab, dmin
 
 
-def _lloyd_kernel(x_ref, c_ref, cn_ref, lab_ref, dist_ref, sum_ref, cnt_ref,
+def _assign_kernel(x_ref, c_ref, lab_ref, dist_ref, *, k_real: int):
+    d, row = _distances(x_ref[...], c_ref[...], k_real)
+    lab, dmin = _argmin_rows(d, row)
+    lab_ref[...] = lab
+    dist_ref[...] = dmin
+
+
+def _lloyd_kernel(x_ref, c_ref, lab_ref, dist_ref, sum_ref, cnt_ref,
                   *, k_real: int, n_real: int, block_n: int):
     i = pl.program_id(0)
     x = x_ref[...].astype(jnp.float32)            # (BN, F)
-    c = c_ref[...].astype(jnp.float32)            # (Kp, F)
-    cn = cn_ref[...]                              # (1, Kp) ||c||^2
-    d, col = _distances(x, c, cn, k_real)
-    lab = jnp.argmin(d, axis=1).astype(jnp.int32)         # (BN,)
-    row = jax.lax.broadcasted_iota(jnp.int32, d.shape, 0) # (BN, Kp)
-    valid = row + i * block_n < n_real            # padded rows masked out
+    d, row = _distances(x, c_ref[...], k_real)
+    lab, dmin = _argmin_rows(d, row)              # (1, BN) each
+    lane = jax.lax.broadcasted_iota(jnp.int32, lab.shape, 1)
+    valid = lane + i * block_n < n_real           # padded clients masked
     lab_ref[...] = lab
-    dist_ref[...] = jnp.where(valid[:, 0], jnp.min(d, axis=1), 0.0)
-    onehot = ((col == lab[:, None]) & valid).astype(jnp.float32)  # (BN, Kp)
+    dist_ref[...] = jnp.where(valid, dmin, 0.0)
+    onehot = ((row == lab) & valid).astype(jnp.float32)   # (Kp, BN)
     # partial assign+update: every grid step maps to the same (Kp, F) /
-    # (1, Kp) output block, so += across the sequential grid reduces N
+    # (Kp, 1) output block, so += across the sequential grid reduces N
     @pl.when(i == 0)
     def _():
         sum_ref[...] = jnp.zeros_like(sum_ref)
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
     sum_ref[...] += jax.lax.dot_general(
-        onehot, x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)       # (Kp, F) = onehot^T @ x
-    cnt_ref[...] += jnp.sum(onehot, axis=0, keepdims=True)
+        onehot, x, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)       # (Kp, F) = onehot @ x
+    cnt_ref[...] += jnp.sum(onehot, axis=1, keepdims=True)
 
 
 def _pad_to(x, m, axis, value=0.0):
@@ -99,83 +117,71 @@ def _pad_to(x, m, axis, value=0.0):
 
 
 def _padded(x, c, block_n):
-    xp = _pad_to(_pad_to(x, block_n, 0), 128, 1)
-    cp = _pad_to(_pad_to(c, 128, 0), 128, 1)
-    cn = jnp.sum(cp.astype(jnp.float32) ** 2, axis=1)[None, :]  # (1, Kp)
-    return xp, cp, cn
+    return (_pad_to(_pad_to(x, block_n, 0), 128, 1),
+            _pad_to(_pad_to(c, 128, 0), 128, 1))
+
+
+def _specs(block_n, kp, fp):
+    """Feature tile per grid step, centroids resident, per-client outputs
+    as lane-dense (1, block_n) slices of a (1, Npad) row."""
+    ins = [pl.BlockSpec((block_n, fp), lambda i: (i, 0)),
+           pl.BlockSpec((kp, fp), lambda i: (0, 0))]
+    row = pl.BlockSpec((1, block_n), lambda i: (0, i))
+    return ins, [row, row]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def kmeans_assign(x: jnp.ndarray, c: jnp.ndarray, *, block_n: int = 128,
-                  interpret: bool | None = None):
-    """x: (N, F), c: (K, F) -> (labels (N,) int32, min_dist (N,) f32).
-
-    ``interpret=None`` probes the backend (compiled on TPU only)."""
-    interpret = _resolve_interpret(interpret)
-    n, f = x.shape
+                  interpret: bool = False):
+    """x: (N, F), c: (K, F) -> (labels (N,) int32, min_dist (N,) f32)."""
+    n, _ = x.shape
     k = c.shape[0]
-    xp, cp, cn = _padded(x, c, block_n)
+    xp, cp = _padded(x, c, block_n)
     kp = cp.shape[0]
     npad, fp = xp.shape
-    grid = (npad // block_n,)
-
+    in_specs, out_specs = _specs(block_n, kp, fp)
     labels, dists = pl.pallas_call(
         functools.partial(_assign_kernel, k_real=k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, fp), lambda i: (i, 0)),   # feature tile
-            pl.BlockSpec((kp, fp), lambda i: (0, 0)),        # centroids resident
-            pl.BlockSpec((1, kp), lambda i: (0, 0)),         # ||c||^2
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-        ],
+        grid=(npad // block_n,),
+        in_specs=in_specs,
+        out_specs=out_specs,
         out_shape=[
-            jax.ShapeDtypeStruct((npad,), jnp.int32),
-            jax.ShapeDtypeStruct((npad,), jnp.float32),
+            jax.ShapeDtypeStruct((1, npad), jnp.int32),
+            jax.ShapeDtypeStruct((1, npad), jnp.float32),
         ],
         interpret=interpret,
-    )(xp, cp, cn)
-    return labels[:n], dists[:n]
+    )(xp, cp)
+    return labels[0, :n], dists[0, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def lloyd_step(x: jnp.ndarray, c: jnp.ndarray, *, block_n: int = 128,
-               interpret: bool | None = None):
+               interpret: bool = False):
     """Fused Lloyd assign+update. x: (N, F), c: (K, F) ->
     (labels (N,) int32, min_dist (N,) f32, sums (K, F) f32, counts (K,) f32)
     where sums[k] = sum of features assigned to k and counts[k] their count
     — one grid pass over N, no second (N, K) one-hot matmul."""
-    interpret = _resolve_interpret(interpret)
     n, f = x.shape
     k = c.shape[0]
-    xp, cp, cn = _padded(x, c, block_n)
+    xp, cp = _padded(x, c, block_n)
     kp = cp.shape[0]
     npad, fp = xp.shape
-    grid = (npad // block_n,)
-
+    in_specs, out_specs = _specs(block_n, kp, fp)
     labels, dists, sums, counts = pl.pallas_call(
         functools.partial(_lloyd_kernel, k_real=k, n_real=n,
                           block_n=block_n),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, fp), lambda i: (i, 0)),   # feature tile
-            pl.BlockSpec((kp, fp), lambda i: (0, 0)),        # centroids resident
-            pl.BlockSpec((1, kp), lambda i: (0, 0)),         # ||c||^2
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+        grid=(npad // block_n,),
+        in_specs=in_specs,
+        out_specs=out_specs + [
             pl.BlockSpec((kp, fp), lambda i: (0, 0)),        # accumulators
-            pl.BlockSpec((1, kp), lambda i: (0, 0)),
+            pl.BlockSpec((kp, 1), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((npad,), jnp.int32),
-            jax.ShapeDtypeStruct((npad,), jnp.float32),
+            jax.ShapeDtypeStruct((1, npad), jnp.int32),
+            jax.ShapeDtypeStruct((1, npad), jnp.float32),
             jax.ShapeDtypeStruct((kp, fp), jnp.float32),
-            jax.ShapeDtypeStruct((1, kp), jnp.float32),
+            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(xp, cp, cn)
-    return labels[:n], dists[:n], sums[:k, :f], counts[0, :k]
+    )(xp, cp)
+    return labels[0, :n], dists[0, :n], sums[:k, :f], counts[:k, 0]

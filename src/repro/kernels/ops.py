@@ -1,6 +1,8 @@
-"""jit'd public wrappers for the Pallas kernels with backend dispatch:
-interpret mode on CPU (this container), compiled Pallas on real TPU,
-pure-jnp reference as an always-available fallback.
+"""jit'd public wrappers for the Pallas kernels with platform dispatch:
+compiled Pallas on TPU, the Pallas interpreter or a jnp formulation on
+other backends, and the pure-jnp references on request (``impl="ref"``).
+This is the one place that asks which backend runs; the kernels
+themselves compile for the TPU unless told to interpret.
 """
 from __future__ import annotations
 
@@ -23,7 +25,7 @@ def kmeans_assign(x, c, *, impl: str = "auto"):
                          and not _on_tpu()):
         # interpret-mode pallas is slow for very large N on CPU
         return REF.kmeans_assign_ref(x, c)
-    labels, _ = _kmeans_pallas(x, c)   # interpret probed per backend
+    labels, _ = _kmeans_pallas(x, c, interpret=not _on_tpu())
     return labels
 
 
@@ -46,12 +48,12 @@ def lloyd_step(x, c, *, impl: str = "auto"):
 
     impl: auto — compiled Pallas on TPU, fused jnp elsewhere (interpret
     mode pays a per-tile interpreter cost that defeats the fusion on CPU);
-    pallas — force the kernel (interpret probed per backend); ref — the
-    naive (N, K, F)-broadcast oracle."""
+    pallas — force the kernel (interpreted off TPU); ref — the naive
+    (N, K, F)-broadcast oracle."""
     if impl == "ref":
         return REF.lloyd_step_ref(x, c)
     if impl == "pallas" or (impl == "auto" and _on_tpu()):
-        return _lloyd_pallas(x, c)     # interpret probed per backend
+        return _lloyd_pallas(x, c, interpret=not _on_tpu())
     return _lloyd_step_jnp(x, c)
 
 

@@ -29,7 +29,9 @@ def lloyd_step_ref(x: jnp.ndarray, c: jnp.ndarray):
     d = ((x32[:, None, :] - c32[None, :, :]) ** 2).sum(-1)
     lab = jnp.argmin(d, axis=1).astype(jnp.int32)
     onehot = jax.nn.one_hot(lab, c.shape[0], dtype=jnp.float32)
-    return lab, d.min(axis=1), onehot.T @ x32, onehot.sum(0)
+    # f32 on every backend (the TPU's default would round x32 to bf16)
+    sums = jnp.matmul(onehot.T, x32, precision=jax.lax.Precision.HIGHEST)
+    return lab, d.min(axis=1), sums, onehot.sum(0)
 
 
 def flash_attention_ref(q, k, v, *, causal: bool = True,

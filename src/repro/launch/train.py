@@ -83,6 +83,7 @@ from repro.core.adapters import cnn_adapter, transformer_adapter
 from repro.core.server import FederatedServer
 from repro.data.partition import partition_clients
 from repro.data.synthetic import make_image_dataset, make_token_dataset
+from repro.launch.compile_cache import use_compile_cache
 
 
 def run_paper(args) -> dict:
@@ -455,6 +456,7 @@ def main():
                          "raises at the offending op")
     args = ap.parse_args()
 
+    use_compile_cache()
     obs.configure(jsonl=args.log_jsonl, csv=args.log_csv,
                   quiet=args.quiet)
     with obs.maybe_profile(args.profile_dir):

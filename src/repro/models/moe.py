@@ -40,14 +40,11 @@ def init_moe(key, cfg):
 
 def _num_groups(total_tokens: int) -> int:
     """Groups = data-axis size when the ambient mesh divides the tokens."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is None or am.empty or "data" not in am.axis_names:
-            return 1
-        g = dict(zip(am.axis_names, am.axis_sizes))["data"]
-        return g if total_tokens % g == 0 else 1
-    except Exception:
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty or "data" not in am.axis_names:
         return 1
+    g = dict(zip(am.axis_names, am.axis_sizes))["data"]
+    return g if total_tokens % g == 0 else 1
 
 
 def moe_capacity(tokens_per_group: int, cfg) -> int:

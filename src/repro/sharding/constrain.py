@@ -45,24 +45,14 @@ def _filter_entry(mesh_axes, entry):
 
 
 def maybe_constrain(x, *axes):
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return x
-    if am is None or am.empty:
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
         return x
     names = set(am.axis_names)
     names -= getattr(_STATE, "forbidden", frozenset())
-    try:
-        # inside a shard_map manual region the manual axes (e.g. 'pod' in
-        # the FL-round step) must not appear in sharding constraints
-        manual = {n for n, t in zip(am.axis_names, am.axis_types)
-                  if str(t) == "Manual"}
-        names -= manual
-    except Exception:
-        pass
+    # inside a shard_map manual region the manual axes (e.g. 'pod' in the
+    # FL-round step) must not appear in sharding constraints
+    names -= {n for n, t in zip(am.axis_names, am.axis_types)
+              if t == jax.sharding.AxisType.Manual}
     spec = P(*[_filter_entry(names, a) for a in axes])
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, spec)

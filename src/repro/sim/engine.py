@@ -74,6 +74,19 @@ def _chunk_width(c: int, width: int) -> int:
     return w
 
 
+def _varying(tree):
+    """Mark a replicated tree as varying over the ``data`` axis inside a
+    ``shard_map`` body: the local-SGD scan carry (params and optimizer
+    state) starts replicated and comes back per-shard, and a scan's carry
+    must keep one type."""
+    return jax.tree.map(
+        lambda a: jax.lax.pcast(a, ("data",), to="varying"), tree)
+
+
+def _same(tree):
+    return tree
+
+
 def _client_map(fn, args: Tuple[jnp.ndarray, ...], width: int):
     """Map ``fn`` over the leading client axis of every array in ``args``:
     vmap in ``width``-wide chunks under an outer ``lax.map`` (see module
@@ -143,21 +156,22 @@ class CohortEngine:
         return apply
 
     def _local_scan(self, params0, opt_init, opt_update, xb, yb, mask,
-                    global_params, proximal: bool):
-        """Scan ``local_step`` over the step axis for one client."""
+                    global_params, proximal: bool, vary=_same):
+        """Scan ``local_step`` over the step axis for one client.
+        ``vary`` maps the initial carry (``_varying`` inside shard_map)."""
         upd = self._masked_step(opt_update, proximal, global_params)
 
         def step(carry, inp):
             xs, ys, m = inp
             return upd(*carry, xs, ys, m), None
 
-        (p, _), _ = jax.lax.scan(step, (params0, opt_init(params0)),
+        (p, _), _ = jax.lax.scan(step, vary((params0, opt_init(params0))),
                                  (xb, yb, mask))
         return p
 
     def _local_scan_gather(self, params0, opt_init, opt_update, x_row,
                            y_row, plan, mask, global_params,
-                           proximal: bool):
+                           proximal: bool, vary=_same):
         """The device-resident twin of :meth:`_local_scan`: the scan
         carries the client's resident (n_cap, *feat) data and gathers
         each step's (bs,) minibatch by plan indices — the padded
@@ -171,11 +185,11 @@ class CohortEngine:
             ys = jnp.take(y_row, idx, axis=0)
             return upd(*carry, xs, ys, m), None
 
-        (p, _), _ = jax.lax.scan(step, (params0, opt_init(params0)),
+        (p, _), _ = jax.lax.scan(step, vary((params0, opt_init(params0))),
                                  (plan, mask))
         return p
 
-    def _build_train_core(self):
+    def _build_train_core(self, vary=_same):
         """Shared round-training body used by both the single-device and
         the mesh-mapped builders: per-client local scans (chunked vmap)
         plus the f32 weighted FedAvg partial.  Returns (stacked, partial)
@@ -190,7 +204,7 @@ class CohortEngine:
 
             def one_client(cx, cy, cm):
                 return self._local_scan(global_params, init, upd, cx, cy,
-                                        cm, global_params, proximal)
+                                        cm, global_params, proximal, vary)
 
             stacked = _client_map(one_client, (xb, yb, mask),
                                   cfg.cohort_vmap_width)
@@ -252,10 +266,7 @@ class CohortEngine:
         stays on the single-device program)."""
         from repro.sharding.rules import (cohort_bucket_specs,
                                           cohort_param_spec)
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:   # pre-0.6 jax keeps it under experimental
-            from jax.experimental.shard_map import shard_map
-        core = self._build_train_core()
+        core = self._build_train_core(vary=_varying)
 
         def shard_body(global_params, xb, yb, mask, weights):
             stacked, partial = core(global_params, xb, yb, mask, weights)
@@ -266,13 +277,13 @@ class CohortEngine:
                 lambda p, s: jax.lax.psum(p, "data").astype(s.dtype),
                 partial, stacked)
 
-        train = shard_map(
+        train = jax.shard_map(
             shard_body, mesh=self.mesh,
             in_specs=(cohort_param_spec(),) + cohort_bucket_specs(),
             out_specs=cohort_param_spec())
         return jax.jit(train)
 
-    def _build_train_gather_core(self):
+    def _build_train_gather_core(self, vary=_same):
         """Round-training body for the device-resident fleet path: take
         the winners' rows out of the class store, run the same chunked
         vmap/scan as the bucket path with per-step index gathers, and
@@ -293,7 +304,8 @@ class CohortEngine:
             def one_client(x_row, y_row, plan, m):
                 return self._local_scan_gather(global_params, init, upd,
                                                x_row, y_row, plan, m,
-                                               global_params, proximal)
+                                               global_params, proximal,
+                                               vary)
 
             stacked = _client_map(one_client, (xg, yg, plans, mask),
                                   cfg.cohort_vmap_width)
@@ -343,10 +355,7 @@ class CohortEngine:
         the FedAvg partial is psum-reduced on-mesh."""
         from repro.sharding.rules import (cohort_param_spec,
                                           fleet_class_specs)
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:   # pre-0.6 jax keeps it under experimental
-            from jax.experimental.shard_map import shard_map
-        core = self._build_train_gather_core()
+        core = self._build_train_gather_core(vary=_varying)
 
         def shard_body(global_params, class_x, class_y, rows, plans,
                        mask, weights):
@@ -356,7 +365,7 @@ class CohortEngine:
                 lambda p, g: jax.lax.psum(p, "data").astype(g.dtype),
                 partial, global_params)
 
-        train = shard_map(
+        train = jax.shard_map(
             shard_body, mesh=self.mesh,
             in_specs=(cohort_param_spec(),) + fleet_class_specs(),
             out_specs=cohort_param_spec())

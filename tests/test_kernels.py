@@ -34,15 +34,20 @@ def test_kmeans_assign_matches_ref(n, f, k, dtype):
 
 
 def test_kmeans_assign_backend_probe_default():
-    """interpret=None (the default) probes the backend — off-TPU it must
-    resolve to interpret mode and agree with the oracle, so call sites no
-    longer hard-code interpret=True."""
+    """The kernel compiles for the TPU unless told to interpret: off the
+    TPU its default must raise rather than fall back to the interpreter,
+    and the platform probe lives in ops (whose default agrees with the
+    oracle here)."""
     kx, kc = jax.random.split(KEY)
     x = jax.random.normal(kx, (130, 48))
     c = jax.random.normal(kc, (5, 48))
-    lab, dist = kmeans_assign(x, c)          # no interpret argument
+    assert jax.default_backend() != "tpu"
+    with pytest.raises(Exception, match="(?i)interpret"):
+        kmeans_assign(x, c)                  # no interpret argument
+    lab = ops.kmeans_assign(x, c, impl="pallas")
     np.testing.assert_array_equal(np.asarray(lab),
                                   np.asarray(ref.kmeans_assign_ref(x, c)))
+    _, dist = kmeans_assign(x, c, interpret=True)
     np.testing.assert_allclose(np.asarray(dist),
                                np.asarray(ref.kmeans_min_dist_ref(x, c)),
                                rtol=1e-4, atol=1e-4)
@@ -147,14 +152,14 @@ def test_jnp_flash_vjp_matches_naive_autodiff():
 
 def test_kmeans_inside_lloyd_converges():
     """Pallas assignment inside Lloyd's recovers 4 well-separated blobs
-    (interpret selected by the backend probe, not hard-coded)."""
+    (interpret selected by the ops platform dispatch, not hard-coded)."""
     from repro.core.clustering import kmeans
     rng = np.random.default_rng(0)
     centers = rng.normal(size=(4, 16)) * 10
     pts = np.concatenate([c + rng.normal(size=(50, 16)) for c in centers])
     labels, cent = kmeans(
         jnp.asarray(pts, jnp.float32), 4, jax.random.PRNGKey(0),
-        assign_fn=lambda x, c: kmeans_assign(x, c)[0])
+        assign_fn=lambda x, c: ops.kmeans_assign(x, c, impl="pallas"))
     lab = np.asarray(labels).reshape(4, 50)
     for g in range(4):
         assert len(np.unique(lab[g])) == 1   # each blob in one cluster
