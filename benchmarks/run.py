@@ -324,12 +324,14 @@ def bench_round_pipeline(quick: bool):
     device-resident ``device`` runtime on the full server loop (stage-2
     control plane + stage-3 training + async metric buffering), with the
     per-round cost split into ``host_pack_s`` (numpy gather / index
-    assembly) and ``device_s`` (everything else: dispatch + compute +
-    any retraces).  The fleet is imbalanced and the scheme picks a fresh
-    random cohort each round, so the vectorized packer keeps meeting new
-    bucket shapes — the realistic regime the capacity-class policy is
-    built for; retrace/hit counters from ``engine.stats`` make the
-    "zero retraces after warm-up" claim auditable in the JSON."""
+    assembly: the ``cohort/pack`` and ``cohort/assemble`` spans) and
+    ``device_s`` (everything else: dispatch + compute + any retraces).
+    The fleet is imbalanced and the scheme picks a fresh random cohort
+    each round, so the vectorized packer keeps meeting new bucket shapes
+    — the realistic regime the capacity-class policy is built for;
+    ``obs.jax_stats`` retrace/hit counters make the "zero retraces after
+    warm-up" claim auditable in the JSON."""
+    from repro import obs
     from repro.configs.base import FLConfig
     from repro.core.adapters import cnn_adapter
     from repro.core.server import FederatedServer
@@ -362,22 +364,25 @@ def bench_round_pipeline(quick: bool):
         # the first rounds' programs — all outside the timed window
         srv.run(rounds=warm_rounds)
         jax.block_until_ready(srv.params)
-        stats0 = dict(srv.runtime.engine.stats)
-        srv.runtime.host_pack_s = 0.0
+        stats0 = obs.jax_stats.snapshot()
+        sink = obs.configure(memory=True)
         t0 = time.time()
         for t in range(warm_rounds, warm_rounds + timed_rounds):
             srv._dispatch_round(t, eval_now=False)   # the round pipeline
         srv._flush_pending()
         jax.block_until_ready(srv.params)
         wall = time.time() - t0
-        stats1 = srv.runtime.engine.stats
+        d = obs.jax_stats.delta(stats0)
+        obs.OBS.reset()
+        pack_s = sum(e["dur_s"] for e in sink.events
+                     if e["kind"] == "span"
+                     and e["name"] in ("cohort/pack", "cohort/assemble"))
         row = {
             "rounds_per_s": timed_rounds / wall,
-            "host_pack_s": srv.runtime.host_pack_s,
-            "device_s": wall - srv.runtime.host_pack_s,
-            "retraces_warm": stats1["traces"] - stats0["traces"],
-            "new_shapes_warm": (stats1["shape_misses"]
-                                - stats0["shape_misses"]),
+            "host_pack_s": pack_s,
+            "device_s": wall - pack_s,
+            "retraces_warm": d.get("traces/cohort_engine", 0),
+            "new_shapes_warm": d.get("shape_misses", 0),
         }
         out[rt] = row
         _row(f"round_pipeline_{rt}", wall / timed_rounds * 1e6,

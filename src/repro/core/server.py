@@ -445,7 +445,8 @@ class FederatedServer:
                 new_state, win, metrics = self._round_step(self.state,
                                                            self._next_key())
                 # the one unconditional per-round fetch (explicit, counted)
-                win_np = obs.device_get(win)
+                with obs.span("round/winner_fetch", round=t):
+                    win_np = obs.device_get(win)
                 sel_idx = np.nonzero(win_np)[0]
 
             # stage 3: local training + aggregation (cohort runtime
@@ -586,8 +587,9 @@ class FederatedServer:
                  metrics) = self._round_step(self.state, self.dyn_state,
                                              self._next_key(),
                                              self._next_dyn_key())
-                win_np, out_np, next_avail = obs.device_get(
-                    (win, outcome, new_dyn.avail))
+                with obs.span("round/winner_fetch", round=t):
+                    win_np, out_np, next_avail = obs.device_get(
+                        (win, outcome, new_dyn.avail))
                 sel_idx = np.nonzero(win_np)[0]
             completed, late, dropped = DYN.split_outcomes(sel_idx, out_np)
             self.outcome_log.append(out_np[sel_idx])

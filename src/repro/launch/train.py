@@ -444,8 +444,6 @@ def main():
                     help="write the structured obs event stream (round "
                          "series, spans, jax counters) as JSON lines; "
                          "validate with `python -m repro.obs.schema`")
-    ap.add_argument("--log-csv", default=None, metavar="PATH",
-                    help="flat CSV mirror of the obs event stream")
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="capture a jax.profiler trace of the whole run "
                          "for TensorBoard/Perfetto")
@@ -457,8 +455,7 @@ def main():
     args = ap.parse_args()
 
     use_compile_cache()
-    obs.configure(jsonl=args.log_jsonl, csv=args.log_csv,
-                  quiet=args.quiet)
+    obs.configure(jsonl=args.log_jsonl, quiet=args.quiet)
     with obs.maybe_profile(args.profile_dir):
         result = {"paper": run_paper, "transformer": run_transformer,
                   "selection": run_selection}[args.mode](args)

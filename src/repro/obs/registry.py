@@ -5,12 +5,15 @@ sinks only at the caller's logging boundaries.
 The registry is the process-wide singleton :data:`OBS`.  Everything is a
 no-op while no sink is attached (``OBS.enabled`` is False — the default),
 so instrumented hot paths pay one attribute load + branch; with sinks the
-cost per record is a dict append to a host-side buffer.  Nothing here
-imports jax and nothing ever touches device values: callers hand the
-registry plain Python scalars they already fetched at their own sync
-points, which is what keeps instrumentation from perturbing the async
-round pipeline (no extra blocking fetches, no changed dispatch order —
-asserted by tests/test_obs.py).
+cost per record is a dict append to a host-side buffer.  Spans and the
+stage-3 work counts are taken while ``OBS.recording``: a sink is
+attached, or a profiler capture opened by :func:`repro.obs.maybe_profile`
+is running (the spans then reach the profiler's trace without a sink).
+Nothing here imports jax and nothing ever touches device values: callers
+hand the registry plain Python scalars they already fetched at their own
+sync points, which is what keeps instrumentation from perturbing the
+async round pipeline (no extra blocking fetches, no changed dispatch
+order — asserted by tests/test_obs.py).
 
 Event stream shape (one dict per event; the JSONL sink writes one per
 line, schema in :mod:`repro.obs.schema`):
@@ -29,6 +32,7 @@ header records the wall-clock epoch for absolute-time reconstruction.
 """
 from __future__ import annotations
 
+import contextlib
 import sys
 import threading
 import time
@@ -55,12 +59,30 @@ class Observability:
         self.counters: Dict[str, float] = {}
         self.gauges: Dict[str, float] = {}
         self._dirty_counters: set = set()
+        self._profiling = 0
         self.quiet = False
 
     # -- lifecycle -----------------------------------------------------
     @property
     def enabled(self) -> bool:
         return bool(self._sinks)
+
+    @property
+    def recording(self) -> bool:
+        """Spans and work counts are taken: a sink is attached, or a
+        profiler capture is running (:meth:`profiling`)."""
+        return bool(self._sinks) or self._profiling > 0
+
+    @contextlib.contextmanager
+    def profiling(self):
+        """Mark a profiler capture: spans record inside it with no sink."""
+        with self._lock:
+            self._profiling += 1
+        try:
+            yield
+        finally:
+            with self._lock:
+                self._profiling -= 1
 
     def add_sink(self, sink) -> None:
         with self._lock:
@@ -73,18 +95,15 @@ class Observability:
             if hook not in self._flush_hooks:
                 self._flush_hooks.append(hook)
 
-    def configure(self, jsonl: Optional[str] = None,
-                  csv: Optional[str] = None, memory: bool = False,
+    def configure(self, jsonl: Optional[str] = None, memory: bool = False,
                   quiet: Optional[bool] = None):
         """Attach sinks from CLI-style options.  Returns the MemorySink
         when ``memory`` is requested (tests read its ``events``)."""
-        from repro.obs.sinks import CsvSink, JsonlSink, MemorySink
+        from repro.obs.sinks import JsonlSink, MemorySink
         mem = None
         with self._lock:
             if jsonl:
                 self.add_sink(JsonlSink(jsonl))
-            if csv:
-                self.add_sink(CsvSink(csv))
             if memory:
                 mem = MemorySink()
                 self.add_sink(mem)

@@ -2,7 +2,7 @@
 
 All sinks consume batches of event dicts at flush time; none are touched
 from the hot path.  File sinks sanitize non-finite floats to ``null`` so
-every line/row stays strictly-valid JSON/CSV (NaN is how the server logs
+every line stays strictly-valid JSON (NaN is how the server logs
 off-cadence eval rounds — see FederatedServer.run)."""
 from __future__ import annotations
 
@@ -50,34 +50,6 @@ class JsonlSink:
         for e in batch:
             self._f.write(json.dumps(sanitize_event(e), sort_keys=False))
             self._f.write("\n")
-        self._f.flush()
-
-    def close(self) -> None:
-        self._f.close()
-
-
-class CsvSink:
-    """Flat CSV (``--log-csv``): fixed columns for the common fields,
-    everything else JSON-packed into ``extra`` so no event loses data."""
-
-    COLUMNS = ("kind", "ts", "name", "round", "value", "t0", "dur_s",
-               "id", "parent", "depth", "extra")
-
-    def __init__(self, path: str):
-        self.path = path
-        self._f = open(path, "w")
-        self._f.write(",".join(self.COLUMNS) + "\n")
-
-    def emit(self, batch: List[Dict[str, Any]]) -> None:
-        for raw in batch:
-            e = sanitize_event(raw)
-            extra = {k: v for k, v in e.items() if k not in self.COLUMNS}
-            cells = []
-            for col in self.COLUMNS[:-1]:
-                v = e.get(col)
-                cells.append("" if v is None else json.dumps(v))
-            cells.append(json.dumps(json.dumps(extra)) if extra else "")
-            self._f.write(",".join(cells) + "\n")
         self._f.flush()
 
     def close(self) -> None:

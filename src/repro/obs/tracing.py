@@ -4,10 +4,15 @@
 ``{"kind": "span", name, id, parent, depth, t0, dur_s, **meta}`` event on
 exit.  Spans nest through a thread-local stack (each thread traces its
 own tree), use the monotonic clock (registry epoch), and are safe to
-leave in hot paths: with no sink attached ``span()`` returns a shared
-null context manager (one branch + one attribute load per call), and
-when enabled the cost is two ``perf_counter`` reads plus one buffered
-dict append at exit — no I/O, no device sync.
+leave in hot paths: while nothing records (``OBS.recording`` is False)
+``span()`` returns a shared null context manager (one branch + one
+attribute load per call); while recording the cost is two
+``perf_counter`` reads, one ``jax.profiler.TraceAnnotation`` and one
+buffered dict append at exit — no I/O, no device sync.
+
+The annotation carries exactly the span's name, so under a profiler
+capture the span's host interval lies on the device trace's clock and a
+device idle gap can be attributed to the innermost span the host was in.
 
 The async server records *dispatch* spans (``round/dispatch`` and its
 children) separately from *drain* spans (``round/drain``): a dispatch
@@ -19,6 +24,8 @@ from __future__ import annotations
 
 import itertools
 import threading
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.registry import OBS, now
 
@@ -40,7 +47,7 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "meta", "t0", "sid", "parent")
+    __slots__ = ("name", "meta", "t0", "sid", "parent", "ann")
 
     def __init__(self, name, meta):
         self.name = name
@@ -53,11 +60,14 @@ class _Span:
         self.parent = stack[-1].sid if stack else None
         self.sid = next(_ids)
         stack.append(self)
+        self.ann = TraceAnnotation(self.name)
+        self.ann.__enter__()
         self.t0 = now()
         return self
 
     def __exit__(self, *exc):
         t1 = now()
+        self.ann.__exit__(*exc)
         stack = _tls.stack
         depth = len(stack) - 1
         if stack and stack[-1] is self:
@@ -73,10 +83,10 @@ _RESERVED = frozenset(("kind", "ts", "name", "id", "parent", "depth",
 
 
 def span(name: str, **meta):
-    """Open a span; a no-op shared context manager while obs is
-    disabled.  ``meta`` must be JSON-serializable host scalars; keys
+    """Open a span; a no-op shared context manager while nothing
+    records.  ``meta`` must be JSON-serializable host scalars; keys
     clashing with the span schema fields are prefixed ``meta_``."""
-    if not OBS.enabled:
+    if not OBS.recording:
         return _NULL
     if _RESERVED & meta.keys():
         meta = {(f"meta_{k}" if k in _RESERVED else k): v

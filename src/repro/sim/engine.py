@@ -35,8 +35,9 @@ runtimes).
     per-round host work is index assembly only — no sample ever crosses
     host->device after init.  Capacity classes are static (derived from
     the whole fleet at init), so these programs compile once per class;
-    ``CohortEngine.stats`` counts traces and per-shape cache hits/misses
-    to make "zero retraces after warm-up" assertable.
+    ``obs.jax_stats`` counts traces (``traces/cohort_engine``) and
+    per-shape cache hits/misses to make "zero retraces after warm-up"
+    assertable.
 
 ``jax.jit`` retraces per distinct bucket shape ``(C, S, bs)``; the packer
 pads C to a multiple of the vmap chunk width, S to a multiple of 4, and
@@ -105,10 +106,8 @@ class CohortEngine:
         self.adapter = adapter
         self.cfg = cfg
         self.mesh = mesh
-        # compile bookkeeping for the round-training programs: ``traces``
-        # increments inside the traced bodies (runs only when XLA
-        # (re)compiles); hits/misses track per-call shape-signature reuse.
-        self.stats = {"traces": 0, "shape_hits": 0, "shape_misses": 0}
+        # per-call shape signatures seen, for obs.jax_stats' hit/miss
+        # counters
         self._seen_shapes = set()
         self._train = self._build_train()      # jitted inside the builder
         self._train_sharded = (self._build_train_sharded()
@@ -129,14 +128,17 @@ class CohortEngine:
         """Client-axis shard count (1 when unsharded)."""
         return 1 if self.mesh is None else self.mesh.shape["data"]
 
+    def client_chunks(self, rows: int, sharded: bool) -> int:
+        """Client chunks that a round-training program over ``rows``
+        clients runs one after another on each device: the ``lax.map``
+        of :func:`_client_map` over the rows a device holds (all of
+        them, or a ``data``-axis share for the mesh-mapped program)."""
+        local = rows // (self.data_axis_size if sharded else 1)
+        return local // _chunk_width(local, self.cfg.cohort_vmap_width)
+
     def _note_shape(self, key) -> None:
-        hit = key in self._seen_shapes
-        if hit:
-            self.stats["shape_hits"] += 1
-        else:
-            self._seen_shapes.add(key)
-            self.stats["shape_misses"] += 1
-        obs.jax_stats.note_shape(hit)   # process-wide mirror
+        obs.jax_stats.note_shape(key in self._seen_shapes)
+        self._seen_shapes.add(key)
 
     # ------------------------------------------------------------------
     def _masked_step(self, opt_update, proximal: bool, global_params):
@@ -199,7 +201,6 @@ class CohortEngine:
         proximal = cfg.aggregator == "fedprox"
 
         def core(global_params, xb, yb, mask, weights):
-            self.stats["traces"] += 1      # runs at trace time only
             obs.jax_stats.note_trace("cohort_engine")
 
             def one_client(cx, cy, cm):
@@ -296,7 +297,6 @@ class CohortEngine:
 
         def core(global_params, class_x, class_y, rows, plans, mask,
                  weights):
-            self.stats["traces"] += 1      # runs at trace time only
             obs.jax_stats.note_trace("cohort_engine")
             xg = jnp.take(class_x, rows, axis=0)   # (C, n_cap, *feat)
             yg = jnp.take(class_y, rows, axis=0)

@@ -40,7 +40,7 @@ pipeline.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List
+from typing import Callable, Dict, List
 
 import jax.numpy as jnp
 import numpy as np
@@ -216,3 +216,18 @@ class FleetStore:
                     b.client_idx[r] = gid
                 out.append(b)
         return out
+
+
+def class_work(batches: List[ClassBatch],
+               chunks: Callable[[int], int]) -> Dict[str, int]:
+    """What a round's class calls run: ``calls``; ``serial_steps``, the
+    local-SGD steps they run one after another (a call runs its class's
+    ``step_cap`` steps, masked or not, once per client chunk:
+    ``chunks(tier)``); ``step_slots``, the client-step slots they carry
+    (tier x step_cap); ``steps_real``, the unmasked ones."""
+    return {"calls": len(batches),
+            "serial_steps": sum(b.step_mask.shape[1]
+                                * chunks(b.step_mask.shape[0])
+                                for b in batches),
+            "step_slots": sum(b.step_mask.size for b in batches),
+            "steps_real": int(sum(b.step_mask.sum() for b in batches))}

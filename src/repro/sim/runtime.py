@@ -28,7 +28,6 @@ engine backends are tested against (tests/test_sim.py).
 """
 from __future__ import annotations
 
-import time
 from typing import Any, List, Optional, Protocol
 
 import jax
@@ -43,7 +42,7 @@ from repro.optim import apply_updates, fedprox_grad, sgd
 from repro.sim.cohort import (HostPlanCache, drop_zero_size_winners,
                               pack_cohort, pack_feature_pass)
 from repro.sim.engine import CohortEngine
-from repro.sim.fleet import FleetStore
+from repro.sim.fleet import FleetStore, class_work
 
 RUNTIMES = ("sequential", "vectorized", "sharded", "device")
 
@@ -201,17 +200,13 @@ class VectorizedRuntime(SequentialRuntime):
         # memoized plan structure + per-client local data shards: packing
         # rebuilds only the shuffle permutations per round
         self.plan_cache = HostPlanCache(x, y, clients, cfg.local_epochs)
-        self.host_pack_s = 0.0   # cumulative host-side packing time
 
     def _pack(self, sel_idx, history, client_multiple=1):
-        t0 = time.perf_counter()
         with obs.span("cohort/pack", winners=int(np.asarray(sel_idx).size)):
-            buckets = pack_cohort(self.x, self.y, self.clients, sel_idx,
-                                  history, self.cfg,
-                                  client_multiple=client_multiple,
-                                  cache=self.plan_cache)
-        self.host_pack_s += time.perf_counter() - t0
-        return buckets
+            return pack_cohort(self.x, self.y, self.clients, sel_idx,
+                               history, self.cfg,
+                               client_multiple=client_multiple,
+                               cache=self.plan_cache)
 
     def train_cohort(self, global_params, sel_idx, history):
         with obs.span("cohort/train", runtime=self.name,
@@ -364,16 +359,27 @@ class DeviceRuntime(VectorizedRuntime):
         obs.device_put is what makes the warm loop pass the sync auditor
         (implicit numpy->jit transfers are disallowed there) and keeps
         the byte accounting honest."""
-        rows, plans, mask, w = obs.device_put(
-            (b.rows, b.plans, b.step_mask, b.weights))
+        with obs.span("cohort/put"):
+            rows, plans, mask, w = obs.device_put(
+                (b.rows, b.plans, b.step_mask, b.weights))
         return c.x, c.y, rows, plans, mask, w
 
-    def train_cohort(self, global_params, sel_idx, history):
-        t0 = time.perf_counter()
+    def _assemble(self, sel_idx, history, sharded: bool):
+        """The round's class batches.  While obs records, adds what
+        their calls will run to ``obs.jax_stats`` (``stage3/``, one
+        ``assemblies`` a call), after the ``cohort/assemble`` span so
+        that the span times the plan alone."""
         with obs.span("cohort/assemble",
                       winners=int(np.asarray(sel_idx).size)):
             batches = self.store.assemble(sel_idx, np.asarray(history))
-        self.host_pack_s += time.perf_counter() - t0
+        if obs.OBS.recording:
+            obs.jax_stats.note_work("stage3", assemblies=1, **class_work(
+                batches,
+                lambda rows: self.engine.client_chunks(rows, sharded)))
+        return batches
+
+    def train_cohort(self, global_params, sel_idx, history):
+        batches = self._assemble(sel_idx, history, sharded=True)
         with obs.span("cohort/train", runtime=self.name,
                       classes=len(batches)):
             agg = None
@@ -386,11 +392,8 @@ class DeviceRuntime(VectorizedRuntime):
             return agg
 
     def train_cohort_updates(self, global_params, sel_idx, history):
-        t0 = time.perf_counter()
-        with obs.span("cohort/assemble",
-                      winners=int(np.asarray(sel_idx).size)):
-            batches = self.store.assemble(sel_idx, np.asarray(history))
-        self.host_pack_s += time.perf_counter() - t0
+        # the updates program is always the single-device one
+        batches = self._assemble(sel_idx, history, sharded=False)
         if not batches:
             return None
         with obs.span("cohort/train", runtime=self.name,

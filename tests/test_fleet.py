@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs.base import FLConfig
 from repro.core.adapters import cnn_adapter
 from repro.core.server import FederatedServer
@@ -139,10 +140,13 @@ def test_device_runtime_zero_retrace_across_shifting_cohorts(data,
     adapter = cnn_adapter("mnist")
     params = adapter.init(jax.random.PRNGKey(0))
     rt = make_runtime(cfg, adapter, train.x, train.y, clients)
+    st0 = obs.jax_stats.snapshot()
     rt.warmup(params)
-    warm = dict(rt.engine.stats)
-    assert warm["traces"] == sum(len(c.tiers) for c in rt.store.classes)
+    warm = obs.jax_stats.delta(st0)
+    assert warm["traces/cohort_engine"] == sum(len(c.tiers)
+                                               for c in rt.store.classes)
     hist = np.zeros(N_CLIENTS, np.int64)
+    st1 = obs.jax_stats.snapshot()
     # 3+ rounds with shifting cohort sizes AND compositions, including
     # one bigger than any tier (chunked invocations reuse the shapes)
     for sel in (np.arange(N_CLIENTS), np.array([0, 3]),
@@ -150,10 +154,76 @@ def test_device_runtime_zero_retrace_across_shifting_cohorts(data,
         p = rt.train_cohort(params, sel, hist)
         assert p is not None
         hist[sel] += 1
-    after = rt.engine.stats
-    assert after["traces"] == warm["traces"], (warm, after)
-    assert after["shape_misses"] == warm["shape_misses"], (warm, after)
-    assert after["shape_hits"] > warm["shape_hits"]
+    after = obs.jax_stats.delta(st1)
+    assert "traces/cohort_engine" not in after, (warm, after)
+    assert "shape_misses" not in after, (warm, after)
+    assert after["shape_hits"] > 0
+
+
+def test_stage3_counts_equal_the_class_batches(data, clients,
+                                               monkeypatch):
+    """The stage-3 work counts (fleet.class_work) against the ClassBatch
+    arrays, and the device runtime adding them to obs.jax_stats only
+    while obs records."""
+    import repro.sim.runtime as RT
+    from repro.sim.fleet import class_work
+    train, _ = data
+    cfg = _cfg(runtime="device", cohort_vmap_width=2)
+    adapter = cnn_adapter("mnist")
+    params = adapter.init(jax.random.PRNGKey(0))
+    rt = make_runtime(cfg, adapter, train.x, train.y, clients)
+    sel, hist = np.arange(N_CLIENTS), np.zeros(N_CLIENTS, np.int64)
+    batches = rt.store.assemble(sel, hist)
+    want = {"calls": len(batches), "serial_steps": 0, "step_slots": 0,
+            "steps_real": 0}
+    for b in batches:
+        tier, cap = b.step_mask.shape
+        assert cap == rt.store.classes[b.cls_id].step_cap
+        width = 2 if tier % 2 == 0 else 1     # vmap width dividing tier
+        want["serial_steps"] += cap * tier // width
+        want["step_slots"] += tier * cap
+        want["steps_real"] += int((b.step_mask > 0).sum())
+    got = class_work(batches, lambda r: rt.engine.client_chunks(r, True))
+    assert got == want
+    assert want["steps_real"] < want["step_slots"]
+
+    calls = []
+    monkeypatch.setattr(RT, "class_work",
+                        lambda *a: calls.append(a) or class_work(*a))
+    st = obs.jax_stats.snapshot()
+    rt.train_cohort(params, sel, hist)           # obs off: no counting
+    assert calls == []
+    obs.configure(memory=True)
+    try:
+        rt.train_cohort(params, sel, hist)
+    finally:
+        obs.OBS.reset()
+    moved = {k: v for k, v in obs.jax_stats.delta(st).items()
+             if k.startswith("stage3/")}
+    assert moved == {"stage3/assemblies": 1,
+                     **{f"stage3/{k}": v for k, v in want.items()}}
+
+
+def test_device_trace_program_names(data):
+    """bench/metrics/*_device_ms_per_round find the round step, the class
+    programs and the eval by these module names on the device trace's
+    'XLA Modules' line."""
+    from repro.core import rounds as RND
+    srv = _server(_cfg(runtime="device"), data)
+    rt = srv.runtime
+    b = rt.store.warmup_batches()[0]
+
+    def name(lowered):
+        return lowered.compiler_ir().operation.attributes["sym_name"].value
+
+    assert name(RND._round_step_jit.lower(
+        srv.state, srv.key, None, None, srv.cfg,
+        "segmented")) == "jit__round_step_jit"
+    assert name(rt.engine._train_gather.lower(
+        srv.params, *rt._put_batch(b, rt.store.classes[b.cls_id]))
+    ) == "jit_train"
+    assert name(srv._eval_step.lower(srv.params,
+                                     srv._test_dev)) == "jit__eval"
 
 
 # ----------------------------------------------------------------------

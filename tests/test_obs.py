@@ -159,6 +159,65 @@ def test_jax_stats_counters_and_transfer_accounting():
     assert d.get("traces/t_test") == 1
 
 
+def test_compile_seconds_move_on_a_fresh_jit_only():
+    f = jax.jit(lambda x: x * 3 + 1)
+    x = obs.device_put(np.ones((5,), np.float32))
+    st = obs.jax_stats.snapshot()
+    f(x).block_until_ready()
+    d = obs.jax_stats.delta(st)
+    assert d["compile_s"] > 0
+    phases = d["compile/trace_s"] + d["compile/lower_s"] \
+        + d["compile/backend_s"]
+    assert d["compile_s"] <= phases + 1e-9     # the union, not the sum
+    st = obs.jax_stats.snapshot()
+    f(x).block_until_ready()                   # cached: nothing compiles
+    assert not any(k.startswith("compile")
+                   for k in obs.jax_stats.delta(st))
+
+
+def _host_spans(trace_dir):
+    """The host-plane events of the trace under ``trace_dir`` named as
+    the program's round spans: name -> (start_ns, end_ns)."""
+    from jax.profiler import ProfileData
+    path = sorted(trace_dir.rglob("*.xplane.pb"))[-1]
+    out = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("round/"):
+                    out[e.name] = (e.start_ns, e.end_ns)
+    return out
+
+
+@pytest.mark.parametrize("mode", ["sink", "off", "maybe_profile"])
+def test_spans_reach_the_profiler_trace(mode, tmp_path):
+    """While recording, each span is a profiler annotation of its own
+    name (nested as the spans nest); with no sink and no maybe_profile,
+    span() stays the shared null object and the trace holds none."""
+    if mode == "sink":
+        obs.configure(memory=True)
+    capture = (obs.maybe_profile(tmp_path) if mode == "maybe_profile"
+               else jax.profiler.trace(str(tmp_path)))
+    with capture:
+        if mode == "off":
+            assert obs.span("a") is obs.span("b")
+        with obs.span("round/dispatch", round=0):
+            with obs.span("round/select", round=0):
+                with obs.span("round/winner_fetch", round=0):
+                    obs.device_get(obs.device_put(np.ones(3)))
+    assert obs.OBS.recording == (mode == "sink")   # the capture ended
+    spans = _host_spans(tmp_path)
+    if mode == "off":
+        assert spans == {}
+        return
+    (d0, d1), (s0, s1), (w0, w1) = (
+        spans[n] for n in ("round/dispatch", "round/select",
+                           "round/winner_fetch"))
+    assert d0 <= s0 <= w0 < w1 <= s1 <= d1
+
+
 def test_sync_audit_flags_implicit_transfers():
     f = jax.jit(lambda x: x + 1)
     host = np.ones((4,), np.float32)
@@ -203,6 +262,15 @@ def test_verbose_does_not_force_evals(data, clients):
 
 def test_observability_is_neutral_on_device_runtime(data, clients,
                                                     tmp_path):
+    _check_neutral(data, clients, tmp_path, profile=False)
+
+
+def test_observability_is_neutral_under_the_profiler(data, clients,
+                                                     tmp_path):
+    _check_neutral(data, clients, tmp_path, profile=True)
+
+
+def _check_neutral(data, clients, tmp_path, profile):
     rounds = 4
     # uninstrumented twin first (obs disabled via the autouse fixture)
     srv0 = _server(data, clients, runtime="device", eval_every=2)
@@ -220,7 +288,8 @@ def test_observability_is_neutral_on_device_runtime(data, clients,
         srv1._dispatch_round(t, srv1._eval_due(t, final=False))
     srv1._flush_pending()
     st = obs.jax_stats.snapshot()
-    with obs.sync_audit():                  # no implicit host transfers
+    with obs.maybe_profile(tmp_path / "trace" if profile else None), \
+            obs.sync_audit():               # no implicit host transfers
         for t in range(2, rounds):
             srv1._dispatch_round(t, srv1._eval_due(t, final=t == rounds - 1))
     srv1._flush_pending()
@@ -243,7 +312,11 @@ def test_observability_is_neutral_on_device_runtime(data, clients,
     # dispatch and drain are recorded separately
     names = [e["name"] for e in mem.events if e["kind"] == "span"]
     assert names.count("round/dispatch") == rounds
+    assert names.count("round/winner_fetch") == rounds
+    assert "cohort/put" in names
     assert "round/drain" in names
+    if profile:
+        assert "round/winner_fetch" in _host_spans(tmp_path / "trace")
 
 
 def test_schema_validator_catches_violations():
