@@ -17,7 +17,6 @@ These are the federated local models for the paper-faithful reproduction.
 from __future__ import annotations
 
 import math
-from functools import partial
 from typing import Tuple
 
 import jax
@@ -37,27 +36,17 @@ def _fc_init(key, shape):
 
 
 def conv2d(x, w, b, padding="VALID"):
-    """x: (B,H,W,C); w: (kh,kw,cin,cout) — im2col formulation.
+    """x: (B,H,W,C); w: (kh,kw,cin,cout); VALID or SAME padding.
 
-    Expressing the conv as patches @ w lowers to one GEMM: on CPU this is
-    ~2x faster (forward+backward) than lax.conv for these 5x5 kernels,
-    and under the cohort engine's per-client vmap it becomes a batched
-    GEMM instead of XLA's slow grouped-convolution path.
+    XLA's own convolution, at XLA's default precision (on a TPU the
+    operands round to bfloat16 and the sums are float32).  Under the
+    cohort engine's per-client vmap it becomes a grouped convolution.
+    An im2col form (patches @ kernel) was slower on both platforms: on a
+    TPU its patch tensor, with 25 or 250 channels on the 128-lane minor
+    axis, was a lane-sparse concatenate that took 44 % of a round's
+    stage-3 device time, and on a CPU a vmapped SGD step took 1.1-2.4x
+    as long (PERF.md §6).
     """
-    kh, kw, cin, cout = w.shape
-    if padding == "SAME":
-        ph, pw = (kh - 1) // 2, (kw - 1) // 2
-        x = jnp.pad(x, ((0, 0), (ph, kh - 1 - ph), (pw, kw - 1 - pw),
-                        (0, 0)))
-    oh, ow = x.shape[1] - kh + 1, x.shape[2] - kw + 1
-    cols = [x[:, i:i + oh, j:j + ow, :]
-            for i in range(kh) for j in range(kw)]
-    patches = jnp.concatenate(cols, axis=-1)     # (B, oh, ow, kh*kw*cin)
-    return patches @ w.reshape(kh * kw * cin, cout) + b
-
-
-def conv2d_lax(x, w, b, padding="VALID"):
-    """Reference lax.conv path (oracle for conv2d's im2col rewrite)."""
     y = lax.conv_general_dilated(
         x, w, window_strides=(1, 1), padding=padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"))

@@ -4,6 +4,7 @@ shards.  In this process the sharded runtime runs on the 1-device debug
 mesh (same shard_map program, data axis size 1); the forced-8-device CPU
 mesh is exercised by the subprocess test at the bottom (XLA_FLAGS must be
 set before first jax init — see launch/mesh.py)."""
+import functools
 import os
 import subprocess
 import sys
@@ -108,23 +109,61 @@ def test_pack_cohort_masks_and_weights(data):
 
 
 # ----------------------------------------------------------------------
-# CNN hot-path rewrite oracles (im2col conv / reshape maxpool — the
-# engine's vmap path depends on these formulations, see DESIGN.md)
+# CNN hot-path oracles (XLA's conv against im2col, reshape maxpool
+# against reduce_window — the engine's vmap path runs both, DESIGN.md)
 # ----------------------------------------------------------------------
 
-@pytest.mark.parametrize("padding,cin,cout", [
-    ("VALID", 1, 10), ("VALID", 3, 6), ("SAME", 1, 16), ("SAME", 16, 32),
+def _im2col_conv2d(x, w, b, padding="VALID"):
+    """Oracle: the conv as patches @ kernel, the kh*kw shifted views of
+    ``x`` concatenated on the channel axis, in one float32 GEMM."""
+    kh, kw, cin, cout = w.shape
+    if padding == "SAME":
+        ph, pw = (kh - 1) // 2, (kw - 1) // 2
+        x = jnp.pad(x, ((0, 0), (ph, kh - 1 - ph), (pw, kw - 1 - pw),
+                        (0, 0)))
+    oh, ow = x.shape[1] - kh + 1, x.shape[2] - kw + 1
+    patches = jnp.concatenate([x[:, i:i + oh, j:j + ow, :]
+                               for i in range(kh) for j in range(kw)], -1)
+    return jnp.dot(patches, w.reshape(kh * kw * cin, cout),
+                   precision=jax.lax.Precision.HIGHEST) + b
+
+
+def _conv_program(conv, transform, padding):
+    """``conv`` as the engine runs it: alone, vmapped over per-client
+    weights, or differentiated in all three operands."""
+    f = functools.partial(conv, padding=padding)
+    if transform == "vmap":
+        return jax.jit(jax.vmap(f))
+    if transform == "grad":
+        return jax.jit(jax.grad(lambda x, w, b: jnp.sum(f(x, w, b) ** 2),
+                                argnums=(0, 1, 2)))
+    return jax.jit(f)
+
+
+@pytest.mark.parametrize("padding,cin,cout,transform", [
+    pytest.param(*case, transform, id="-".join(map(str, case)) + (
+        "" if transform == "plain" else f"-{transform}"))
+    for transform in ("plain", "vmap", "grad")
+    for case in (("VALID", 1, 10), ("VALID", 3, 6), ("SAME", 1, 16),
+                 ("SAME", 16, 32))
 ])
-def test_conv2d_im2col_matches_lax(padding, cin, cout):
-    from repro.models.cnn import conv2d, conv2d_lax
+def test_conv2d_im2col_matches_lax(padding, cin, cout, transform):
+    """``conv2d`` (XLA's convolution) equals the im2col oracle alone,
+    under the engine's per-client vmap, and in its gradients."""
+    from repro.models.cnn import conv2d
     key = jax.random.PRNGKey(0)
-    x = jax.random.normal(key, (4, 14, 14, cin))
-    w = jax.random.normal(jax.random.fold_in(key, 1), (5, 5, cin, cout))
-    b = jax.random.normal(jax.random.fold_in(key, 2), (cout,))
-    got = conv2d(x, w, b, padding)
-    ref = conv2d_lax(x, w, b, padding)
-    assert got.shape == ref.shape
-    assert float(jnp.max(jnp.abs(got - ref))) < 1e-4
+    lead = (3,) if transform == "vmap" else ()
+    x = jax.random.normal(key, lead + (4, 14, 14, cin))
+    w = jax.random.normal(jax.random.fold_in(key, 1),
+                          lead + (5, 5, cin, cout))
+    b = jax.random.normal(jax.random.fold_in(key, 2), lead + (cout,))
+    got, ref = (jax.tree.leaves(_conv_program(f, transform, padding)(x, w, b))
+                for f in (conv2d, _im2col_conv2d))
+    for g, r in zip(got, ref, strict=True):
+        assert g.shape == r.shape
+        # a gradient sums ~10^3 products of magnitude ~10: relative
+        scale = float(jnp.max(jnp.abs(r))) if transform == "grad" else 1.0
+        assert float(jnp.max(jnp.abs(g - r))) < 1e-4 * scale
 
 
 @pytest.mark.parametrize("h,w", [(24, 24), (7, 7), (14, 10)])
