@@ -1,0 +1,3 @@
+"""``host_plan_ms_per_round`` in the fleet cell, read as
+bench/metrics/host_plan_ms_per_round.py reads it."""
+from bench.metrics.host_plan_ms_per_round import read  # noqa: F401

@@ -129,11 +129,15 @@ def image_shape(variant: str) -> Tuple[int, int, int]:
     return (32, 32, 3) if variant == "cifar" else (28, 28, 1)
 
 
-def cnn_loss(params, batch, variant: str):
+def cnn_nll(params, batch, variant: str):
+    """(B,) per-sample negative log-likelihoods."""
     logits = cnn_logits(params, batch["x"], variant)
     logp = jax.nn.log_softmax(logits)
-    nll = -jnp.take_along_axis(logp, batch["y"][:, None], axis=1)[:, 0]
-    return nll.mean()
+    return -jnp.take_along_axis(logp, batch["y"][:, None], axis=1)[:, 0]
+
+
+def cnn_loss(params, batch, variant: str):
+    return cnn_nll(params, batch, variant).mean()
 
 
 def cnn_accuracy(params, batch, variant: str):

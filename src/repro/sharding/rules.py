@@ -253,9 +253,9 @@ def cohort_stacked_spec():
 
 def fleet_class_specs():
     """Device-resident fleet path (repro.sim.fleet, ``--runtime device``):
-    ``(class_x, class_y, rows, plans, step_mask, weights)``.  The class
-    store tensors are replicated — every device gathers its own winners'
-    rows out of the full store — while the per-invocation index/weight
-    tensors shard their leading client axis over 'data' (the store pads
-    ``client_cap`` to a multiple of the data-axis size)."""
-    return (P(), P(), P(D), P(D), P(D), P(D))
+    ``(class_x, class_y, rows, plans, step_mask, batch_n, weights)``.
+    The class store tensors are replicated — every device gathers its own
+    winners' rows out of the full store — while the per-invocation
+    index/weight tensors shard their leading client axis over 'data' (the
+    store pads ``client_cap`` to a multiple of the data-axis size)."""
+    return (P(), P(), P(D), P(D), P(D), P(D), P(D))

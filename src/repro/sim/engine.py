@@ -141,12 +141,13 @@ class CohortEngine:
         self._seen_shapes.add(key)
 
     # ------------------------------------------------------------------
-    def _masked_step(self, opt_update, proximal: bool, global_params):
+    def _masked_step(self, grad, opt_update, proximal: bool, global_params):
         """One masked local SGD step shared by both scan flavors: a
-        masked (padding) step is the identity on params AND opt state."""
+        masked (padding) step is the identity on params AND opt state.
+        ``grad(p, batch)`` is the step's gradient."""
 
         def apply(p, opt, xs, ys, m):
-            g = self.adapter.grad(p, {"x": xs, "y": ys})
+            g = grad(p, {"x": xs, "y": ys})
             if proximal:
                 g = fedprox_grad(g, p, global_params, self.cfg.fedprox_mu)
             u, opt2 = opt_update(g, opt, p)
@@ -161,7 +162,8 @@ class CohortEngine:
                     global_params, proximal: bool, vary=_same):
         """Scan ``local_step`` over the step axis for one client.
         ``vary`` maps the initial carry (``_varying`` inside shard_map)."""
-        upd = self._masked_step(opt_update, proximal, global_params)
+        upd = self._masked_step(self.adapter.grad, opt_update, proximal,
+                                global_params)
 
         def step(carry, inp):
             xs, ys, m = inp
@@ -172,14 +174,17 @@ class CohortEngine:
         return p
 
     def _local_scan_gather(self, params0, opt_init, opt_update, x_row,
-                           y_row, plan, mask, global_params,
+                           y_row, plan, mask, n, global_params,
                            proximal: bool, vary=_same):
         """The device-resident twin of :meth:`_local_scan`: the scan
         carries the client's resident (n_cap, *feat) data and gathers
         each step's (bs,) minibatch by plan indices — the padded
         (S, bs, *feat) tensor of the host-packed path is never
-        materialized."""
-        upd = self._masked_step(opt_update, proximal, global_params)
+        materialized.  ``n``: the client's real samples per step, the
+        first ``n`` of the batch (``ModelAdapter.masked_grad``)."""
+        upd = self._masked_step(
+            lambda p, batch: self.adapter.masked_grad(p, batch, n),
+            opt_update, proximal, global_params)
 
         def step(carry, inp):
             idx, m = inp
@@ -288,26 +293,28 @@ class CohortEngine:
         """Round-training body for the device-resident fleet path: take
         the winners' rows out of the class store, run the same chunked
         vmap/scan as the bucket path with per-step index gathers, and
-        fuse the f32 weighted FedAvg partial.  Returns (stacked,
-        partial) — callers pick one (XLA drops the unfetched output) and
-        finish the reduction (astype, or psum + astype)."""
+        fuse the f32 weighted FedAvg partial.  ``batch_n (C,)`` holds
+        each row's real samples per step.  Returns (stacked, partial) —
+        callers pick one (XLA drops the unfetched output) and finish the
+        reduction (astype, or psum + astype)."""
         cfg = self.cfg
         init, upd = sgd(cfg.lr, momentum=cfg.local_momentum)
         proximal = cfg.aggregator == "fedprox"
 
         def core(global_params, class_x, class_y, rows, plans, mask,
-                 weights):
+                 batch_n, weights):
             obs.jax_stats.note_trace("cohort_engine")
             xg = jnp.take(class_x, rows, axis=0)   # (C, n_cap, *feat)
             yg = jnp.take(class_y, rows, axis=0)
 
-            def one_client(x_row, y_row, plan, m):
+            def one_client(x_row, y_row, plan, m, n):
                 return self._local_scan_gather(global_params, init, upd,
-                                               x_row, y_row, plan, m,
+                                               x_row, y_row, plan, m, n,
                                                global_params, proximal,
                                                vary)
 
-            stacked = _client_map(one_client, (xg, yg, plans, mask),
+            stacked = _client_map(one_client,
+                                  (xg, yg, plans, mask, batch_n),
                                   cfg.cohort_vmap_width)
             partial = jax.tree.map(
                 lambda leaf: jnp.tensordot(weights,
@@ -322,9 +329,9 @@ class CohortEngine:
         core = self._build_train_gather_core()
 
         def train(global_params, class_x, class_y, rows, plans, mask,
-                  weights):
+                  batch_n, weights):
             _, partial = core(global_params, class_x, class_y, rows,
-                              plans, mask, weights)
+                              plans, mask, batch_n, weights)
             return jax.tree.map(lambda p, g: p.astype(g.dtype),
                                 partial, global_params)
 
@@ -340,9 +347,10 @@ class CohortEngine:
         padded row's params equal the globals)."""
         core = self._build_train_gather_core()
 
-        def train(global_params, class_x, class_y, rows, plans, mask):
+        def train(global_params, class_x, class_y, rows, plans, mask,
+                  batch_n):
             stacked, _ = core(global_params, class_x, class_y, rows,
-                              plans, mask,
+                              plans, mask, batch_n,
                               jnp.zeros((rows.shape[0],), jnp.float32))
             return self._flat_deltas(stacked, global_params)
 
@@ -358,9 +366,9 @@ class CohortEngine:
         core = self._build_train_gather_core(vary=_varying)
 
         def shard_body(global_params, class_x, class_y, rows, plans,
-                       mask, weights):
+                       mask, batch_n, weights):
             _, partial = core(global_params, class_x, class_y, rows,
-                              plans, mask, weights)
+                              plans, mask, batch_n, weights)
             return jax.tree.map(
                 lambda p, g: jax.lax.psum(p, "data").astype(g.dtype),
                 partial, global_params)
@@ -443,7 +451,7 @@ class CohortEngine:
                                    bucket.step_mask)
 
     def train_class(self, global_params, class_x, class_y, rows, plans,
-                    step_mask, weights):
+                    step_mask, batch_n, weights):
         """One capacity-class invocation of the device-resident round
         trainer: ``class_x/class_y`` are the class's resident ``(P,
         n_cap, ...)`` store, the rest are the per-round ``(C_cap, ...)``
@@ -455,10 +463,10 @@ class CohortEngine:
             if self._train_gather_sharded is not None \
             else self._train_gather
         return step(global_params, class_x, class_y, rows, plans,
-                    step_mask, weights)
+                    step_mask, batch_n, weights)
 
     def train_class_updates(self, global_params, class_x, class_y, rows,
-                            plans, step_mask) -> jnp.ndarray:
+                            plans, step_mask, batch_n) -> jnp.ndarray:
         """(C_cap, D) float32 flat deltas for one capacity-class
         invocation — the device runtime's defended-path twin of
         :meth:`train_class`.  Always the single-device program (the
@@ -468,7 +476,7 @@ class CohortEngine:
             self._train_gather_updates = self._build_train_gather_updates()
         self._note_shape(("class_upd", class_x.shape, plans.shape))
         return self._train_gather_updates(global_params, class_x, class_y,
-                                          rows, plans, step_mask)
+                                          rows, plans, step_mask, batch_n)
 
     def weight_features(self, global_params, buckets: List[CohortBucket],
                         num_clients: int) -> jnp.ndarray:

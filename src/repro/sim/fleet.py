@@ -18,8 +18,12 @@ so jit retraces whenever a round's cohort composition shifts.  The
 
 * **Compile once.**  Bucket shapes are replaced by a small static set of
   **capacity classes** derived from the *fleet* at init, not the round's
-  cohort: class key = (batch size, pow2 band of total local steps), step
-  capacity = the class's fleet-wide max (rounded to a multiple of 4),
+  cohort: class key = pow2 band of total local steps, batch size = the
+  largest member's ``min(32, n)`` (a member with a smaller shard pads its
+  batch axis under a per-sample mask, so a fleet's many shards smaller
+  than a batch share the full-batch class of their band instead of one
+  class per size), step capacity = the class's fleet-wide max (rounded
+  to a multiple of 4),
   client capacity = a short pow2 **tier ladder** up to the per-round
   winner bound (each tier rounded to a multiple of the mesh data-axis
   size).  Every possible winner maps to a pre-known class and every
@@ -30,7 +34,10 @@ so jit retraces whenever a round's cohort composition shifts.  The
   largest-fitting-tier chunking).
 
 Padding waste bound: within a class the pow2 step band keeps any member
-below ~2x the steps of the smallest, same as the bucket path; the pow2
+below ~2x the steps of the smallest, same as the bucket path; a member
+whose shard is smaller than the class batch runs its single step per
+epoch over its n samples, the batch's other slots weighing 0 in the loss
+(``ModelAdapter.masked_grad``); the pow2
 tier ladder keeps client-axis padding below 2x the invocation's real
 winner count (exactly the bucket packer's ``next_pow2`` bound; masked
 rows are weight-0 and drop out of the FedAvg sum exactly), at the cost
@@ -85,13 +92,17 @@ class ClassBatch:
     masked out), ``plans (C_cap, step_cap, bs)`` int32 local sample
     indices, ``step_mask (C_cap, step_cap)`` float32, ``weights (C_cap,)``
     float32 *global* FedAvg weights (over all invocations they sum to 1),
-    ``client_idx (C_cap,)`` int32 global ids (-1 for padding).
+    ``batch_n (C_cap,)`` int32 real samples in each of a row's steps
+    (the first ``batch_n`` of its ``bs`` plan slots; ``bs`` where the
+    steps fill the batch), ``client_idx (C_cap,)`` int32 global ids (-1
+    for padding).
     """
 
     cls_id: int
     rows: np.ndarray
     plans: np.ndarray
     step_mask: np.ndarray
+    batch_n: np.ndarray
     weights: np.ndarray
     client_idx: np.ndarray
 
@@ -114,9 +125,8 @@ class FleetStore:
         for i in range(n):
             if self.cache.sizes[i] == 0:     # no steps, no FedAvg mass
                 continue
-            key = (int(self.cache.bs[i]),
-                   _next_pow2(max(int(total_steps[i]), 1)))
-            groups.setdefault(key, []).append(i)
+            groups.setdefault(_next_pow2(max(int(total_steps[i]), 1)),
+                              []).append(i)
 
         # per-round winner bound: k_total overall, but per-cluster floors
         # can push the union above it (num_clusters x K_j)
@@ -125,9 +135,10 @@ class FleetStore:
         mult = max(int(client_multiple), 1)
 
         self.classes: List[CapacityClass] = []
-        for (bs, _band), members in sorted(groups.items()):
+        for _band, members in sorted(groups.items()):
             members = np.asarray(members, np.int64)
             n_cap = int(self.cache.sizes[members].max())
+            bs = int(self.cache.bs[members].max())
             step_cap = _round_up(int(total_steps[members].max()), 4)
             cap = min(len(members), k_bound)
             # pow2 ladder 1, 2, 4, ... up to the winner bound, every tier
@@ -163,6 +174,7 @@ class FleetStore:
             rows=np.zeros((tier,), np.int32),
             plans=np.zeros((tier, c.step_cap, c.bs), np.int32),
             step_mask=np.zeros((tier, c.step_cap), np.float32),
+            batch_n=np.full((tier,), c.bs, np.int32),
             weights=np.zeros((tier,), np.float32),
             client_idx=np.full((tier,), -1, np.int32))
 
@@ -208,10 +220,11 @@ class FleetStore:
                 b = self._empty_batch(cls_id, tier)
                 for r, (gid, p) in enumerate(chunk):
                     plan = self.cache.plan(gid, int(history[gid]))
-                    s = plan.shape[0]
+                    s, n = plan.shape
                     b.rows[r] = self.row_of[gid]
-                    b.plans[r, :s] = plan
+                    b.plans[r, :s, :n] = plan
                     b.step_mask[r, :s] = 1.0
+                    b.batch_n[r] = n
                     b.weights[r] = p
                     b.client_idx[r] = gid
                 out.append(b)
@@ -224,10 +237,17 @@ def class_work(batches: List[ClassBatch],
     local-SGD steps they run one after another (a call runs its class's
     ``step_cap`` steps, masked or not, once per client chunk:
     ``chunks(tier)``); ``step_slots``, the client-step slots they carry
-    (tier x step_cap); ``steps_real``, the unmasked ones."""
+    (tier x step_cap); ``steps_real``, the unmasked ones;
+    ``sample_slots``, the batch-axis slots of those real steps (steps x
+    the class's bs); ``samples_real``, the real samples among them."""
+    steps = [b.step_mask.sum(axis=1) for b in batches]    # per row
     return {"calls": len(batches),
             "serial_steps": sum(b.step_mask.shape[1]
                                 * chunks(b.step_mask.shape[0])
                                 for b in batches),
             "step_slots": sum(b.step_mask.size for b in batches),
-            "steps_real": int(sum(b.step_mask.sum() for b in batches))}
+            "steps_real": int(sum(s.sum() for s in steps)),
+            "sample_slots": int(sum(s.sum() * b.plans.shape[2]
+                                    for s, b in zip(steps, batches))),
+            "samples_real": int(sum((s * b.batch_n).sum()
+                                    for s, b in zip(steps, batches)))}

@@ -337,8 +337,10 @@ class DeviceRuntime(VectorizedRuntime):
         would re-dispatch real masked scans against a hot jit cache."""
         if self._warmed:
             return
-        with obs.span("fleet/warmup", classes=len(self.store.classes)):
-            for b in self.store.warmup_batches():
+        batches = self.store.warmup_batches()
+        with obs.span("fleet/warmup", classes=len(self.store.classes),
+                      programs=len(batches)):
+            for b in batches:
                 c = self.store.classes[b.cls_id]
                 staged = self._put_batch(b, c)
                 if self.cfg.defended:
@@ -346,10 +348,11 @@ class DeviceRuntime(VectorizedRuntime):
                     # instead of the fused one — warm that variant so the
                     # screened path keeps the zero-warm-retrace guarantee
                     jax.block_until_ready(self.engine.train_class_updates(
-                        global_params, *staged[:5]))
+                        global_params, *staged[:6]))
                 else:
                     jax.block_until_ready(self.engine.train_class(
                         global_params, *staged))
+        obs.jax_stats.note_work("fleet", programs=len(batches))
         self._warmed = True
 
     def _put_batch(self, b, c):
@@ -360,9 +363,9 @@ class DeviceRuntime(VectorizedRuntime):
         (implicit numpy->jit transfers are disallowed there) and keeps
         the byte accounting honest."""
         with obs.span("cohort/put"):
-            rows, plans, mask, w = obs.device_put(
-                (b.rows, b.plans, b.step_mask, b.weights))
-        return c.x, c.y, rows, plans, mask, w
+            rows, plans, mask, n, w = obs.device_put(
+                (b.rows, b.plans, b.step_mask, b.batch_n, b.weights))
+        return c.x, c.y, rows, plans, mask, n, w
 
     def _assemble(self, sel_idx, history, sharded: bool):
         """The round's class batches.  While obs records, adds what
@@ -402,7 +405,7 @@ class DeviceRuntime(VectorizedRuntime):
             for b in batches:
                 c = self.store.classes[b.cls_id]
                 parts.append(self.engine.train_class_updates(
-                    global_params, *self._put_batch(b, c)[:5]))
+                    global_params, *self._put_batch(b, c)[:6]))
                 ws.append(np.asarray(b.weights, np.float32))
                 ids.append(np.asarray(b.client_idx, np.int32))
             # padding rows ride along (all-zero delta, id -1, weight 0);

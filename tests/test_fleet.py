@@ -83,11 +83,12 @@ def test_capacity_classes_cover_fleet(data, clients):
             assert store.row_of[gid] == r
             assert gid not in seen
             seen.add(int(gid))
-            # the client's whole plan fits the class capacities
+            # the client's whole plan fits the class capacities: a shard
+            # smaller than the class batch runs one step of n samples
             n = clients[gid].size
-            assert min(32, n) == c.bs
+            assert min(32, n) <= c.bs == min(32, c.n_cap)
             assert n <= c.n_cap
-            total = (n // c.bs) * cfg.local_epochs
+            total = (n // min(32, n)) * cfg.local_epochs
             assert total <= c.step_cap
             # the resident row is exactly the client's local shard
             xl, yl = store.cache.local_data(int(gid))
@@ -175,17 +176,24 @@ def test_stage3_counts_equal_the_class_batches(data, clients,
     sel, hist = np.arange(N_CLIENTS), np.zeros(N_CLIENTS, np.int64)
     batches = rt.store.assemble(sel, hist)
     want = {"calls": len(batches), "serial_steps": 0, "step_slots": 0,
-            "steps_real": 0}
+            "steps_real": 0, "sample_slots": 0, "samples_real": 0}
     for b in batches:
         tier, cap = b.step_mask.shape
-        assert cap == rt.store.classes[b.cls_id].step_cap
+        c = rt.store.classes[b.cls_id]
+        assert cap == c.step_cap
         width = 2 if tier % 2 == 0 else 1     # vmap width dividing tier
         want["serial_steps"] += cap * tier // width
         want["step_slots"] += tier * cap
         want["steps_real"] += int((b.step_mask > 0).sum())
+        for gid, steps in zip(b.client_idx, (b.step_mask > 0).sum(1)):
+            if gid >= 0:
+                want["sample_slots"] += int(steps) * c.bs
+                want["samples_real"] += int(steps) * min(
+                    32, clients[gid].size)
     got = class_work(batches, lambda r: rt.engine.client_chunks(r, True))
     assert got == want
     assert want["steps_real"] < want["step_slots"]
+    assert want["samples_real"] < want["sample_slots"]
 
     calls = []
     monkeypatch.setattr(RT, "class_work",
@@ -281,3 +289,170 @@ def test_run_round_flushes_immediately(data):
     assert srv._pending == []
     assert log.round == 0 and np.isfinite(log.test_acc)
     assert len(srv.logs) == 1
+
+
+# ----------------------------------------------------------------------
+# shards smaller than a batch: one masked-batch class per step band
+# ----------------------------------------------------------------------
+
+MERGED_CLIENTS = 40
+MERGED_POOL = 2000      # varpi 50: shards of 8..80 training samples
+
+
+def _merged_cfg(**kw):
+    return _cfg(num_clients=MERGED_CLIENTS, num_clusters=4,
+                select_ratio=0.25, local_epochs=1, **kw)
+
+
+@pytest.fixture(scope="module")
+def merged():
+    train, _ = make_image_dataset("mnist", n_train=MERGED_POOL, n_test=64,
+                                  seed=5)
+    clients = partition_clients(train.y, _merged_cfg(), seed=5)
+    sizes = np.array([c.size for c in clients])
+    assert sizes.min() < 32 < sizes.max()
+    return train, clients
+
+
+class _Shard:
+    def __init__(self, n):
+        self.train_idx = np.arange(n)
+
+    @property
+    def size(self):
+        return len(self.train_idx)
+
+
+def _size_table(pool: int, n: int) -> np.ndarray:
+    """Training shard sizes of the benchmark's partition
+    (bench/gen/partition.local_sizes over [varpi/6, 2 varpi], then the
+    80 % train split)."""
+    varpi = pool // n
+    lo = max(int(varpi / 6), 10)
+    hi = max(int(varpi * 2.0), lo + 1)
+    k = np.arange(n, dtype=np.float64)
+    sizes = lo + np.floor((hi - lo + 1) * (k + 0.5) / n).astype(np.int64)
+    return (0.8 * sizes).astype(np.int64)
+
+
+def _bands(sizes, epochs=1):
+    steps = sizes // np.minimum(32, sizes) * epochs
+    return {1 << int(max(s, 1) - 1).bit_length() for s in steps}
+
+
+@pytest.mark.parametrize("name,clients,pool,classes,programs", [
+    ("cpu", 40, 2000, None, None),
+    ("paper100", 100, 60000, 5, 21),
+    ("emnist3383", 3383, 341873, 4, 37),
+])
+def test_shards_below_the_batch_share_the_full_batch_class(
+        name, clients, pool, classes, programs):
+    """One class per power-of-two step band, whatever the shard sizes in
+    it: a fleet's shards smaller than a batch pad into its band's
+    full-batch class, and every row states its real samples a step."""
+    sizes = _size_table(pool, clients)
+    x = np.zeros((int(sizes.max()), 1), np.float32)
+    y = np.zeros((int(sizes.max()),), np.int32)
+    cfg = FLConfig(num_clients=clients, num_clusters=10, select_ratio=0.1,
+                   local_epochs=1)
+    store = FleetStore(x, y, [_Shard(int(n)) for n in sizes], cfg)
+    assert len(store.classes) == len(_bands(sizes))
+    if classes is not None:
+        assert len(store.classes) == classes
+        assert sum(len(c.tiers) for c in store.classes) == programs
+    for c in store.classes:
+        n = sizes[c.members]
+        assert c.bs == min(32, int(n.max()))
+    padded = any((np.minimum(32, sizes[c.members]) < c.bs).any()
+                 for c in store.classes)
+    assert padded == bool((sizes < 32).any())
+    # a padded member's plan rows carry its n real samples first
+    batches = store.assemble(np.arange(clients), np.zeros(clients, int))
+    for b in batches:
+        for r, gid in enumerate(b.client_idx):
+            if gid < 0:                     # padding row: a full batch
+                assert b.batch_n[r] == store.classes[b.cls_id].bs
+                continue
+            n = min(32, int(sizes[gid]))
+            assert b.batch_n[r] == n
+            plan = b.plans[r, b.step_mask[r] > 0]
+            assert len(set(plan[0, :n])) == n
+            assert plan[:, :n].max() < sizes[gid]
+            assert (plan[:, n:] == 0).all()
+
+
+def test_device_runtime_matches_sequential_on_padded_batches(merged):
+    """Three rounds of the masked-batch classes against the oracle's
+    per-client loop, each round from the last one's params."""
+    train, clients = merged
+    adapter = cnn_adapter("mnist")
+    params = adapter.init(jax.random.PRNGKey(1))
+    cfg = _merged_cfg()
+    seq = make_runtime(cfg.replace(runtime="sequential"), adapter, train.x,
+                       train.y, clients)
+    dev = make_runtime(cfg.replace(runtime="device"), adapter, train.x,
+                       train.y, clients)
+    assert any(min(32, clients[g].size) < c.bs
+               for c in dev.store.classes for g in c.members)
+    hist = np.zeros(MERGED_CLIENTS, np.int64)
+    p_seq = p_dev = params
+    small = [i for i, c in enumerate(clients) if c.size < 32]
+    for sel in (np.arange(0, MERGED_CLIENTS, 3), np.array(small),
+                np.arange(MERGED_CLIENTS)):
+        p_seq = seq.train_cohort(p_seq, sel, hist)
+        p_dev = dev.train_cohort(p_dev, sel, hist)
+        diff = max(jax.tree.leaves(jax.tree.map(
+            lambda a, b: float(jnp.max(jnp.abs(a - b))), p_seq, p_dev)))
+        assert diff < 1e-4
+        hist[sel] += 1
+
+
+def test_padded_batch_classes_do_not_retrace_after_warmup(merged):
+    train, clients = merged
+    adapter = cnn_adapter("mnist")
+    params = adapter.init(jax.random.PRNGKey(0))
+    rt = make_runtime(_merged_cfg(runtime="device"), adapter, train.x,
+                      train.y, clients)
+    programs = sum(len(c.tiers) for c in rt.store.classes)
+    st0 = obs.jax_stats.snapshot()
+    rt.warmup(params)
+    warm = obs.jax_stats.delta(st0)
+    assert warm["traces/cohort_engine"] == programs
+    assert warm["fleet/programs"] == programs
+    hist = np.zeros(MERGED_CLIENTS, np.int64)
+    st1 = obs.jax_stats.snapshot()
+    rng = np.random.default_rng(0)
+    for k in (1, 3, 7, 10, 2):
+        sel = np.sort(rng.choice(MERGED_CLIENTS, k, replace=False))
+        assert rt.train_cohort(params, sel, hist) is not None
+        hist[sel] += 1
+    after = obs.jax_stats.delta(st1)
+    assert "traces/cohort_engine" not in after, (warm, after)
+    assert "shape_misses" not in after, (warm, after)
+
+
+def test_stage3_sample_counts_add_up(merged):
+    """``stage3/sample_slots`` counts each real step's batch slots and
+    ``stage3/samples_real`` the samples the oracle's batch holds."""
+    train, clients = merged
+    adapter = cnn_adapter("mnist")
+    params = adapter.init(jax.random.PRNGKey(0))
+    rt = make_runtime(_merged_cfg(runtime="device"), adapter, train.x,
+                      train.y, clients)
+    sel = np.arange(MERGED_CLIENTS)
+    hist = np.zeros(MERGED_CLIENTS, np.int64)
+    sizes = np.array([c.size for c in clients])
+    bs = np.minimum(32, sizes)
+    steps = sizes // bs
+    slots = sum(int(steps[i]) * rt.store.classes[rt.store.class_of[i]].bs
+                for i in sel)
+    st = obs.jax_stats.snapshot()
+    obs.configure(memory=True)
+    try:
+        rt.train_cohort(params, sel, hist)
+    finally:
+        obs.OBS.reset()
+    moved = obs.jax_stats.delta(st)
+    assert moved["stage3/samples_real"] == int((steps * bs).sum())
+    assert moved["stage3/sample_slots"] == slots
+    assert moved["stage3/samples_real"] < moved["stage3/sample_slots"]

@@ -113,6 +113,7 @@ def _class_training_lowering(devices) -> tuple:
         jax.ShapeDtypeStruct((tier,), i32),
         jax.ShapeDtypeStruct((tier, step_cap, bs), i32),
         jax.ShapeDtypeStruct((tier, step_cap), f32),
+        jax.ShapeDtypeStruct((tier,), i32),
         jax.ShapeDtypeStruct((tier,), f32)))
     program = (engine._train_gather_sharded if chips > 1
                else engine._train_gather)
