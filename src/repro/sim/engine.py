@@ -28,10 +28,13 @@ runtimes).
   * :meth:`CohortEngine.train_class` — the device-resident twin of
     ``train_bucket`` for the ``device`` runtime (repro.sim.fleet): instead
     of consuming host-packed ``(C, S, bs, ...)`` minibatch tensors it
-    takes a capacity class's resident ``(P, n_cap, *feat)`` store plus
-    tiny per-round int tensors (winner rows + local batch plans) and
-    gathers each step's minibatch *inside* the compiled program
-    (``jnp.take`` by winner row, then a per-step take over the plan), so
+    takes a capacity class's resident ``(P, n_rows, width)`` store of
+    flat sample rows plus tiny per-round int tensors (winner rows + local
+    batch plans) and gathers each step's minibatch *inside* the compiled
+    program (``jnp.take`` by winner row, then a per-step take over the
+    plan, cut to ``prod(feat)`` values and reshaped to ``(bs, *feat)``
+    for the model; both takes clip, exact for the in-range indices
+    ``FleetStore.assemble`` writes), so
     per-round host work is index assembly only — no sample ever crosses
     host->device after init.  Capacity classes are static (derived from
     the whole fleet at init), so these programs compile once per class;
@@ -51,6 +54,7 @@ reassociation (tested in tests/test_sim.py).
 """
 from __future__ import annotations
 
+import math
 from typing import Any, List, Tuple
 
 import jax
@@ -174,22 +178,25 @@ class CohortEngine:
         return p
 
     def _local_scan_gather(self, params0, opt_init, opt_update, x_row,
-                           y_row, plan, mask, n, global_params,
+                           y_row, plan, mask, n, feat, global_params,
                            proximal: bool, vary=_same):
         """The device-resident twin of :meth:`_local_scan`: the scan
-        carries the client's resident (n_cap, *feat) data and gathers
-        each step's (bs,) minibatch by plan indices — the padded
+        carries the client's resident (n_rows, width) sample rows and
+        gathers each step's (bs,) minibatch by plan indices, cut to its
+        first prod(feat) values and reshaped to (bs, *feat) — the padded
         (S, bs, *feat) tensor of the host-packed path is never
         materialized.  ``n``: the client's real samples per step, the
-        first ``n`` of the batch (``ModelAdapter.masked_grad``)."""
+        first ``n`` of the batch (``ModelAdapter.masked_grad``).  Plan
+        indices are in range, so the takes clip (no fill select)."""
         upd = self._masked_step(
             lambda p, batch: self.adapter.masked_grad(p, batch, n),
             opt_update, proximal, global_params)
 
         def step(carry, inp):
             idx, m = inp
-            xs = jnp.take(x_row, idx, axis=0)
-            ys = jnp.take(y_row, idx, axis=0)
+            xs = jnp.take(x_row, idx, axis=0, mode="clip")[
+                ..., :math.prod(feat)].reshape(idx.shape + feat)
+            ys = jnp.take(y_row, idx, axis=0, mode="clip")
             return upd(*carry, xs, ys, m), None
 
         (p, _), _ = jax.lax.scan(step, vary((params0, opt_init(params0))),
@@ -291,27 +298,30 @@ class CohortEngine:
 
     def _build_train_gather_core(self, vary=_same):
         """Round-training body for the device-resident fleet path: take
-        the winners' rows out of the class store, run the same chunked
-        vmap/scan as the bucket path with per-step index gathers, and
-        fuse the f32 weighted FedAvg partial.  ``batch_n (C,)`` holds
-        each row's real samples per step.  Returns (stacked, partial) —
-        callers pick one (XLA drops the unfetched output) and finish the
-        reduction (astype, or psum + astype)."""
+        the winners' rows out of the class store (flat sample rows;
+        ``feat`` is a sample's shape), run the same chunked vmap/scan as
+        the bucket path with per-step index gathers, and fuse the f32
+        weighted FedAvg partial.  ``batch_n (C,)`` holds each row's real
+        samples per step.  Winner rows are in range, so the takes clip: no call
+        reads the store beyond the rows it gathers.  Returns (stacked,
+        partial) — callers pick one (XLA drops the unfetched output) and
+        finish the reduction (astype, or psum + astype)."""
         cfg = self.cfg
         init, upd = sgd(cfg.lr, momentum=cfg.local_momentum)
         proximal = cfg.aggregator == "fedprox"
 
         def core(global_params, class_x, class_y, rows, plans, mask,
-                 batch_n, weights):
+                 batch_n, weights, feat):
             obs.jax_stats.note_trace("cohort_engine")
-            xg = jnp.take(class_x, rows, axis=0)   # (C, n_cap, *feat)
-            yg = jnp.take(class_y, rows, axis=0)
+            # (C, n_rows, width)
+            xg = jnp.take(class_x, rows, axis=0, mode="clip")
+            yg = jnp.take(class_y, rows, axis=0, mode="clip")
 
             def one_client(x_row, y_row, plan, m, n):
                 return self._local_scan_gather(global_params, init, upd,
                                                x_row, y_row, plan, m, n,
-                                               global_params, proximal,
-                                               vary)
+                                               feat, global_params,
+                                               proximal, vary)
 
             stacked = _client_map(one_client,
                                   (xg, yg, plans, mask, batch_n),
@@ -329,13 +339,13 @@ class CohortEngine:
         core = self._build_train_gather_core()
 
         def train(global_params, class_x, class_y, rows, plans, mask,
-                  batch_n, weights):
+                  batch_n, weights, feat):
             _, partial = core(global_params, class_x, class_y, rows,
-                              plans, mask, batch_n, weights)
+                              plans, mask, batch_n, weights, feat)
             return jax.tree.map(lambda p, g: p.astype(g.dtype),
                                 partial, global_params)
 
-        return jax.jit(train)
+        return jax.jit(train, static_argnames="feat")
 
     def _build_train_gather_updates(self):
         """Per-client flat-delta twin of ``_build_train_gather`` for the
@@ -348,13 +358,14 @@ class CohortEngine:
         core = self._build_train_gather_core()
 
         def train(global_params, class_x, class_y, rows, plans, mask,
-                  batch_n):
+                  batch_n, feat):
             stacked, _ = core(global_params, class_x, class_y, rows,
                               plans, mask, batch_n,
-                              jnp.zeros((rows.shape[0],), jnp.float32))
+                              jnp.zeros((rows.shape[0],), jnp.float32),
+                              feat)
             return self._flat_deltas(stacked, global_params)
 
-        return jax.jit(train)
+        return jax.jit(train, static_argnames="feat")
 
     def _build_train_gather_sharded(self):
         """Mesh-mapped twin of ``_build_train_gather``: the class store
@@ -365,19 +376,22 @@ class CohortEngine:
                                           fleet_class_specs)
         core = self._build_train_gather_core(vary=_varying)
 
-        def shard_body(global_params, class_x, class_y, rows, plans,
-                       mask, batch_n, weights):
-            _, partial = core(global_params, class_x, class_y, rows,
-                              plans, mask, batch_n, weights)
-            return jax.tree.map(
-                lambda p, g: jax.lax.psum(p, "data").astype(g.dtype),
-                partial, global_params)
+        def train(global_params, class_x, class_y, rows, plans, mask,
+                  batch_n, weights, feat):
+            def shard_body(params, *args):
+                _, partial = core(params, *args, feat)
+                return jax.tree.map(
+                    lambda p, g: jax.lax.psum(p, "data").astype(g.dtype),
+                    partial, params)
 
-        train = jax.shard_map(
-            shard_body, mesh=self.mesh,
-            in_specs=(cohort_param_spec(),) + fleet_class_specs(),
-            out_specs=cohort_param_spec())
-        return jax.jit(train)
+            return jax.shard_map(
+                shard_body, mesh=self.mesh,
+                in_specs=(cohort_param_spec(),) + fleet_class_specs(),
+                out_specs=cohort_param_spec())(
+                    global_params, class_x, class_y, rows, plans, mask,
+                    batch_n, weights)
+
+        return jax.jit(train, static_argnames="feat")
 
     def _build_weight_features(self):
         cfg = self.cfg
@@ -451,22 +465,25 @@ class CohortEngine:
                                    bucket.step_mask)
 
     def train_class(self, global_params, class_x, class_y, rows, plans,
-                    step_mask, batch_n, weights):
+                    step_mask, batch_n, weights, *, feat):
         """One capacity-class invocation of the device-resident round
         trainer: ``class_x/class_y`` are the class's resident ``(P,
-        n_cap, ...)`` store, the rest are the per-round ``(C_cap, ...)``
-        index/weight tensors (repro.sim.fleet.ClassBatch).  Returns the
-        weighted FedAvg partial over this invocation's winners; partials
-        across invocations just add (weights are global)."""
+        n_rows, width)`` / ``(P, n_rows)`` store of flat sample rows
+        (repro.sim.fleet.CapacityClass), ``feat`` a sample's shape, the
+        rest are the per-round ``(C_cap, ...)`` index/weight
+        tensors (repro.sim.fleet.ClassBatch).  Returns the weighted
+        FedAvg partial over this invocation's winners; partials across
+        invocations just add (weights are global)."""
         self._note_shape(("class", class_x.shape, plans.shape))
         step = self._train_gather_sharded \
             if self._train_gather_sharded is not None \
             else self._train_gather
         return step(global_params, class_x, class_y, rows, plans,
-                    step_mask, batch_n, weights)
+                    step_mask, batch_n, weights, feat=feat)
 
     def train_class_updates(self, global_params, class_x, class_y, rows,
-                            plans, step_mask, batch_n) -> jnp.ndarray:
+                            plans, step_mask, batch_n, *, feat
+                            ) -> jnp.ndarray:
         """(C_cap, D) float32 flat deltas for one capacity-class
         invocation — the device runtime's defended-path twin of
         :meth:`train_class`.  Always the single-device program (the
@@ -476,7 +493,8 @@ class CohortEngine:
             self._train_gather_updates = self._build_train_gather_updates()
         self._note_shape(("class_upd", class_x.shape, plans.shape))
         return self._train_gather_updates(global_params, class_x, class_y,
-                                          rows, plans, step_mask, batch_n)
+                                          rows, plans, step_mask, batch_n,
+                                          feat=feat)
 
     def weight_features(self, global_params, buckets: List[CohortBucket],
                         num_clients: int) -> jnp.ndarray:

@@ -8,13 +8,37 @@ so jit retraces whenever a round's cohort composition shifts.  The
 ``device`` runtime replaces both taxes:
 
 * **Pack once.**  At server init every client's local shard is gathered
-  once into a device-resident per-class store ``(P, n_cap, *feat)``
-  (row-major by client, plus size/step tables).  Per-round cohort
-  assembly is then an on-device ``jnp.take`` by winner rows inside the
-  compiled program — the only thing the host builds per round are tiny
-  int32 index tensors (winner rows + local batch plans, i.e. the oracle's
-  shuffle permutations, which must stay on the host rng to remain
-  bit-compatible with the sequential oracle).
+  once into a device-resident per-class store of flat sample rows
+  ``(P, n_rows, width)`` (row-major by client, plus size/step tables).
+  Per-round cohort assembly is then an on-device ``jnp.take`` by winner
+  rows inside the compiled program — the only thing the host builds per
+  round are tiny int32 index tensors (winner rows + local batch plans,
+  i.e. the oracle's shuffle permutations, which must stay on the host
+  rng to remain bit-compatible with the sequential oracle).
+  The program cuts each step's gathered ``(bs, width)`` minibatch to
+  its ``prod(feat)`` values and reshapes it to ``(bs, *feat)`` just
+  before the model sees it.
+
+* **Read only the winners.**  On a TPU what a row gather reads depends
+  on the store's device layout, which the chip picks per shape to pad
+  the fewest ``(8, 128)`` tiles.  A store of images ``(P, n, 28, 28,
+  1)``, or of flat rows ``(P, n, 784)`` whose axes are not whole tiles,
+  can get a layout that puts the member axis on a tile; every class call
+  then relayouts (for images also casts) the *whole* store, all ``P``
+  members, before it takes the winners' rows: bytes that grow with the
+  fleet, not the cohort.  So a store pads its sample axis to whole sublanes (``n_rows``,
+  the class's largest shard ``n_cap`` rounded up to 8) and its rows to
+  whole lanes (``width``, ``prod(feat)`` rounded up to 128).  Its
+  row-major layout then pads nothing, the chip keeps it, the member
+  axis stays major, and a call reads only the rows it gathers.  The
+  padding costs under 8 samples a member and 112 floats a 784-float
+  row.
+
+* **In range by construction.**  ``assemble`` writes winner rows in
+  ``[0, P)`` (padding rows 0) and plan indices in ``[0, n)`` (padding 0),
+  so the program's takes use ``mode="clip"``: exact for in-range
+  indices, and without the out-of-range fill that ``jnp.take``'s default
+  mode selects over every gathered minibatch.
 
 * **Compile once.**  Bucket shapes are replaced by a small static set of
   **capacity classes** derived from the *fleet* at init, not the round's
@@ -57,17 +81,33 @@ from repro.configs.base import FLConfig
 from repro.core.selection import k_per_cluster
 from repro.sim.cohort import HostPlanCache, _next_pow2, _round_up
 
+# the TPU's float32 tile (sublanes, lanes): a class store's sample axis
+# and row width are whole tiles, so that the chip keeps the store's
+# member axis major (module docstring)
+TILE = (8, 128)
+
+
+def store_shape(members: int, n_cap: int, feat: tuple) -> tuple:
+    """``(P, n_rows, width)`` of the flat class store of ``members``
+    clients whose largest shard holds ``n_cap`` samples of shape
+    ``feat``: both sample axes padded to whole tiles (:data:`TILE`)."""
+    return (members, _round_up(n_cap, TILE[0]),
+            _round_up(int(np.prod(feat)), TILE[1]))
+
 
 @dataclass
 class CapacityClass:
     """One static shape class of the fleet (one compiled program per
     client-capacity tier).
 
-    ``x (P, n_cap, *feat)`` / ``y (P, n_cap)`` are the device-resident
-    local shards of the class's ``P`` members, each padded to the class
-    max size ``n_cap`` (plans never index the padding).  ``tiers`` is
-    the ascending pow2 ladder of padded client-axis sizes an invocation
-    may use (every tier a multiple of the mesh data-axis size).
+    ``x (P, n_rows, width)`` / ``y (P, n_rows)`` are the device-resident
+    local shards of the class's ``P`` members as flat sample rows: a
+    sample of shape ``feat`` is the first ``prod(feat)`` of its row's
+    ``width`` values, and each member is padded past the class max size
+    ``n_cap`` to ``n_rows`` samples (plans never index the padding;
+    :func:`store_shape`).  ``tiers`` is the ascending pow2 ladder of
+    padded client-axis sizes an invocation may use (every tier a
+    multiple of the mesh data-axis size).
     """
 
     bs: int
@@ -75,6 +115,7 @@ class CapacityClass:
     tiers: List[int]         # padded client-axis capacities (ascending)
     n_cap: int
     members: np.ndarray      # (P,) global client ids
+    feat: tuple              # one sample's shape
     x: jnp.ndarray
     y: jnp.ndarray
 
@@ -150,11 +191,13 @@ class FleetStore:
                 t *= 2
             tiers.append(_round_up(cap, mult))
             tiers = sorted(set(tiers))
-            xb = np.zeros((len(members), n_cap) + x.shape[1:], x.dtype)
-            yb = np.zeros((len(members), n_cap), y.dtype)
+            feat = tuple(x.shape[1:])
+            size = int(np.prod(feat))
+            xb = np.zeros(store_shape(len(members), n_cap, feat), x.dtype)
+            yb = np.zeros(xb.shape[:2], y.dtype)
             for r, gid in enumerate(members):
                 xl, yl = self.cache.local_data(int(gid))
-                xb[r, :len(xl)] = xl
+                xb[r, :len(xl), :size] = xl.reshape(len(xl), size)
                 yb[r, :len(yl)] = yl
                 self.class_of[gid] = len(self.classes)
                 self.row_of[gid] = r
@@ -164,7 +207,7 @@ class FleetStore:
             xd, yd = obs.device_put((xb, yb))
             self.classes.append(CapacityClass(
                 bs=bs, step_cap=step_cap, tiers=tiers, n_cap=n_cap,
-                members=members, x=xd, y=yd))
+                members=members, feat=feat, x=xd, y=yd))
 
     # ------------------------------------------------------------------
     def _empty_batch(self, cls_id: int, tier: int) -> ClassBatch:

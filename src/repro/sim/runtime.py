@@ -348,10 +348,10 @@ class DeviceRuntime(VectorizedRuntime):
                     # instead of the fused one — warm that variant so the
                     # screened path keeps the zero-warm-retrace guarantee
                     jax.block_until_ready(self.engine.train_class_updates(
-                        global_params, *staged[:6]))
+                        global_params, *staged[:6], feat=c.feat))
                 else:
                     jax.block_until_ready(self.engine.train_class(
-                        global_params, *staged))
+                        global_params, *staged, feat=c.feat))
         obs.jax_stats.note_work("fleet", programs=len(batches))
         self._warmed = True
 
@@ -389,7 +389,8 @@ class DeviceRuntime(VectorizedRuntime):
             for b in batches:
                 c = self.store.classes[b.cls_id]
                 part = self.engine.train_class(global_params,
-                                               *self._put_batch(b, c))
+                                               *self._put_batch(b, c),
+                                               feat=c.feat)
                 agg = part if agg is None else jax.tree.map(jnp.add, agg,
                                                             part)
             return agg
@@ -405,7 +406,8 @@ class DeviceRuntime(VectorizedRuntime):
             for b in batches:
                 c = self.store.classes[b.cls_id]
                 parts.append(self.engine.train_class_updates(
-                    global_params, *self._put_batch(b, c)[:6]))
+                    global_params, *self._put_batch(b, c)[:6],
+                    feat=c.feat))
                 ws.append(np.asarray(b.weights, np.float32))
                 ids.append(np.asarray(b.client_idx, np.int32))
             # padding rows ride along (all-zero delta, id -1, weight 0);

@@ -90,10 +90,18 @@ def test_capacity_classes_cover_fleet(data, clients):
             assert n <= c.n_cap
             total = (n // min(32, n)) * cfg.local_epochs
             assert total <= c.step_cap
-            # the resident row is exactly the client's local shard
+            # the resident flat rows, reshaped to the image shape, are
+            # exactly the client's local shard; the padding is zero
             xl, yl = store.cache.local_data(int(gid))
-            assert (np.asarray(c.x[r, :n]) == xl).all()
+            size = int(np.prod(c.feat))
+            row = np.asarray(c.x[r])
+            assert (row[:n, :size].reshape((n,) + c.feat) == xl).all()
+            assert not row[n:].any() and not row[:, size:].any()
             assert (np.asarray(c.y[r, :n]) == yl).all()
+        # flat sample rows, padded to whole (8, 128) tiles
+        assert c.feat == train.x.shape[1:]
+        assert c.x.shape == (len(c.members), -(-c.n_cap // 8) * 8, 896)
+        assert c.y.shape == c.x.shape[:2]
         assert c.tiers == sorted(set(c.tiers))
         assert c.step_cap % 4 == 0
     assert seen == {i for i in range(N_CLIENTS) if clients[i].size > 0}
@@ -227,9 +235,9 @@ def test_device_trace_program_names(data):
     assert name(RND._round_step_jit.lower(
         srv.state, srv.key, None, None, srv.cfg,
         "segmented")) == "jit__round_step_jit"
+    c = rt.store.classes[b.cls_id]
     assert name(rt.engine._train_gather.lower(
-        srv.params, *rt._put_batch(b, rt.store.classes[b.cls_id]))
-    ) == "jit_train"
+        srv.params, *rt._put_batch(b, c), feat=c.feat)) == "jit_train"
     assert name(srv._eval_step.lower(srv.params,
                                      srv._test_dev)) == "jit__eval"
 
@@ -379,6 +387,48 @@ def test_shards_below_the_batch_share_the_full_batch_class(
             assert len(set(plan[0, :n])) == n
             assert plan[:, :n].max() < sizes[gid]
             assert (plan[:, n:] == 0).all()
+
+
+@pytest.mark.parametrize("multiple", [1, 4])
+def test_assembled_rows_and_plans_index_inside_the_store(merged, multiple):
+    """The class programs take winner rows and plan samples with
+    ``mode="clip"``, which equals the default fill only for in-range
+    indices: every row ``assemble`` (or the warm-up) writes lies in
+    ``[0, P)`` and every plan index in ``[0, n)`` of its member's ``n``
+    samples (padding rows and slots index 0), here with shards below a
+    batch, winners chunked at each class's top tier, and tiers rounded
+    to a 4-device mesh's multiple."""
+    train, clients = merged
+    store = FleetStore(train.x, train.y, clients, _merged_cfg(),
+                       client_multiple=multiple)
+    sizes = np.array([c.size for c in clients])
+    hist = np.arange(MERGED_CLIENTS) % 4
+    batches = store.assemble(np.arange(MERGED_CLIENTS), hist)
+    assert any(len(b.rows) == store.classes[b.cls_id].client_cap
+               for b in batches)
+    assert (multiple > 1) == any((b.client_idx < 0).any() for b in batches)
+    batches += store.warmup_batches()
+    assert any(n < store.classes[store.class_of[g]].bs
+               for g, n in enumerate(sizes))
+
+    def take(c, b, mode):
+        """The class program's takes: winner rows, then plan samples."""
+        xs = jnp.take(c.x, b.rows, axis=0, mode=mode)
+        return np.asarray(jax.vmap(
+            lambda x, p: jnp.take(x, p, axis=0, mode=mode))(xs, b.plans))
+
+    for b in batches:
+        c = store.classes[b.cls_id]
+        assert ((0 <= b.rows) & (b.rows < len(c.members))).all()
+        assert b.plans.min() >= 0
+        for r, gid in enumerate(b.client_idx):
+            if gid < 0:
+                assert b.rows[r] == 0 and not b.plans[r].any()
+                continue
+            assert c.members[b.rows[r]] == gid
+            assert b.plans[r].max() < sizes[gid]
+        # so the clip takes read what the fill takes would
+        assert (take(c, b, "clip") == take(c, b, "fill")).all()
 
 
 def test_device_runtime_matches_sequential_on_padded_batches(merged):

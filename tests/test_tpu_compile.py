@@ -11,6 +11,7 @@ only one process at a time may load libtpu, and test workers import every
 test file.
 """
 import functools
+import math
 import os
 import re
 
@@ -28,6 +29,7 @@ from repro.kernels import ops
 from repro.kernels.kmeans import kmeans_assign, lloyd_step
 from repro.models import cnn as CNN
 from repro.sim.engine import CohortEngine
+from repro.sim.fleet import store_shape
 
 K = 10
 # im2col patches: a concatenate with a floating-point result (the gathers'
@@ -94,22 +96,24 @@ def _specs(sharding, tree):
         a.shape, a.dtype, sharding=sharding), tree)
 
 
-def _class_training_lowering(devices) -> tuple:
-    """(convolutions, patch concatenates) of the device runtime's
-    class-training program at a paper-job class shape (bs 32, step cap
-    8, tier 4 per chip): the one-chip program, or the cohort mesh's
-    shard_map program over ``devices``."""
+FEAT = CNN.image_shape("mnist")
+
+
+def _class_program_text(devices, store_x, tier: int, step_cap: int) -> str:
+    """Optimized HLO of the device runtime's class-training program (bs
+    32) over a class store of flat sample rows ``store_x (P, n_rows,
+    width)``: the one-chip program, or the cohort mesh's shard_map
+    program over ``devices``."""
     chips = len(devices)
     mesh = Mesh(np.array(devices).reshape(chips, 1), ("data", "model"))
     engine = CohortEngine(cnn_adapter("mnist"), FLConfig(),
                           mesh=mesh if chips > 1 else None)
-    members, n_cap, tier, step_cap, bs = 6, 64, 4 * chips, 8, 32
-    f32, i32 = jnp.float32, jnp.int32
+    bs, f32, i32 = 32, jnp.float32, jnp.int32
     store, client = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
     args = _specs(store, (
         jax.eval_shape(lambda: CNN.init_cnn(jax.random.PRNGKey(0), "mnist")),
-        jax.ShapeDtypeStruct((members, n_cap, 28, 28, 1), f32),
-        jax.ShapeDtypeStruct((members, n_cap), i32))) + _specs(client, (
+        jax.ShapeDtypeStruct(store_x, f32),
+        jax.ShapeDtypeStruct(store_x[:2], i32))) + _specs(client, (
         jax.ShapeDtypeStruct((tier,), i32),
         jax.ShapeDtypeStruct((tier, step_cap, bs), i32),
         jax.ShapeDtypeStruct((tier, step_cap), f32),
@@ -117,7 +121,15 @@ def _class_training_lowering(devices) -> tuple:
         jax.ShapeDtypeStruct((tier,), f32)))
     program = (engine._train_gather_sharded if chips > 1
                else engine._train_gather)
-    return _cnn_lowering(program.lower(*args).compile().as_text())
+    return program.lower(*args, feat=FEAT).compile().as_text()
+
+
+def _class_training_lowering(devices) -> tuple:
+    """(convolutions, patch concatenates) of the class-training program
+    at a paper-job class shape (6 members of up to 64 samples, step cap
+    8, tier 4 per chip)."""
+    return _cnn_lowering(_class_program_text(
+        devices, store_shape(6, 64, FEAT), 4 * len(devices), 8))
 
 
 def test_class_training_program_convolves_for_v5e(topo):
@@ -132,6 +144,34 @@ def test_mesh_class_training_program_convolves_for_v5e(topo):
     FedAvg psum-reduced on the mesh)."""
     convs, patches = _class_training_lowering(topo.devices)
     assert convs > 0 and patches == 0
+
+
+def _store_copies(text: str, members: int) -> list:
+    """Instructions, parameters aside, with float data whose shape leads
+    with a class store's ``members`` axis: whole-store copies."""
+    lead = re.compile(rf"= (?:f32|bf16)\[{members},\d+,")
+    return [line for line in text.splitlines()
+            if lead.search(line) and " parameter(" not in line]
+
+
+@pytest.mark.parametrize("tiled", [True, False], ids=["store", "untiled"])
+def test_fleet_class_program_reads_only_winner_rows_for_v5e(topo, tiled):
+    """The fleet cell's largest class (1,448 members of 128 to 159
+    training samples, tier 64, step cap 4): its one-chip program copies
+    no whole store, whose bytes would grow with the fleet, and no take
+    fills out-of-range indices (``jit(_take)/select_n``).  Control: flat
+    rows that are not whole (8, 128) tiles, ``(P, 159, 784)``, get a
+    layout with the member axis on a tile, and every call relayouts the
+    whole store."""
+    members, n_cap = 1448, 159
+    store_x = (store_shape(members, n_cap, FEAT) if tiled
+               else (members, n_cap, math.prod(FEAT)))
+    text = _class_program_text(topo.devices[:1], store_x, 64, 4)
+    if tiled:
+        assert _store_copies(text, members) == []
+        assert "jit(_take)/select_n" not in text
+    else:
+        assert _store_copies(text, members)
 
 
 def _im2col_conv2d(x, w, b, padding="VALID"):
