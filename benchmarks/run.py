@@ -53,7 +53,7 @@ def _git_commit() -> str:
 def _summary(name, **headline):
     """Write the top-level ``BENCH_<name>.json`` perf-trajectory summary:
     the benchmark's headline numbers stamped with wall time + commit, so
-    ``git log -p BENCH_round_pipeline.json`` IS the perf history."""
+    ``git log -p BENCH_fleet_dynamics.json`` IS the perf history."""
     rec = {"bench": name, "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S"),
            "commit": _git_commit(), **headline}
     path = os.path.join(REPO_ROOT, f"BENCH_{name}.json")
@@ -200,205 +200,6 @@ def bench_selection(quick: bool):
     _summary("selection", N=top["N"], T=top["T"],
              warm_rounds_per_s=top["fused_rounds_per_s"],
              compile_s=top["compile_s"], speedup=top.get("speedup"))
-
-
-# ----------------------------------------------------------------------
-# micro: cohort execution engine (repro.sim)
-# ----------------------------------------------------------------------
-
-def bench_cohort_engine(quick: bool):
-    """Sequential per-client loop vs the vectorized cohort engine
-    (repro.sim) at several cohort sizes: one full cohort of local
-    training + FedAvg aggregation per call, identical shuffles/batches
-    in both backends."""
-    from repro.configs.base import FLConfig
-    from repro.core.adapters import cnn_adapter
-    from repro.data.partition import partition_clients
-    from repro.data.synthetic import make_image_dataset
-    from repro.sim.runtime import make_runtime
-
-    cohorts = [2, 4, 8, 16] if quick else [2, 4, 8, 16, 32, 64]
-    nclients = max(cohorts)
-    # near-uniform shards (~130 train samples -> 4 steps/client) keep the
-    # comparison about execution, not about padding waste
-    cfg = FLConfig(num_clients=nclients, num_clusters=1, local_epochs=1,
-                   imbalance_low=0.9, imbalance_high=1.1, seed=0)
-    train, _ = make_image_dataset("mnist", n_train=nclients * 165,
-                                  n_test=64, seed=0)
-    clients = partition_clients(train.y, cfg, seed=0)
-    adapter = cnn_adapter("mnist")
-    params = adapter.init(jax.random.PRNGKey(0))
-    history = np.zeros((nclients,), np.int64)
-    seq = make_runtime(cfg.replace(runtime="sequential"), adapter,
-                       train.x, train.y, clients)
-    vec = make_runtime(cfg.replace(runtime="vectorized"), adapter,
-                       train.x, train.y, clients)
-    out = {}
-    for c in cohorts:
-        sel = np.arange(c)
-        us_s = _t(lambda: seq.train_cohort(params, sel, history),
-                  n=3, warmup=1)
-        us_v = _t(lambda: vec.train_cohort(params, sel, history),
-                  n=3, warmup=1)
-        speedup = us_s / us_v
-        steps = sum((clients[i].size - min(32, clients[i].size))
-                    // min(32, clients[i].size) + 1 for i in range(c))
-        _row(f"cohort_engine_seq_C{c}", us_s, f"steps={steps}")
-        _row(f"cohort_engine_vec_C{c}", us_v, f"speedup={speedup:.2f}x")
-        out[c] = {"seq_us": us_s, "vec_us": us_v, "speedup": speedup}
-    _save("cohort_engine", out)
-    top = out[max(out)]
-    _summary("cohort_engine", cohort=max(out), vec_us=top["vec_us"],
-             speedup=top["speedup"])
-
-
-# ----------------------------------------------------------------------
-# micro: sharded cohort runtime (repro.sim, mesh-mapped stage-3)
-# ----------------------------------------------------------------------
-
-def bench_cohort_sharded(quick: bool):
-    """Vectorized (1-device) vs sharded (mesh-mapped) cohort training on
-    whatever devices this process sees.  On a plain host the cohort mesh
-    degrades to 1 device (the bench then measures shard_map overhead);
-    CI runs it under ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-    to exercise the real 8-way client-axis split + psum reduction.  Every
-    row also checks the sharded aggregate against the vectorized one
-    (same float-reassociation tolerance class as tests/test_sim.py)."""
-    from repro.configs.base import FLConfig
-    from repro.core.adapters import cnn_adapter
-    from repro.data.partition import partition_clients
-    from repro.data.synthetic import make_image_dataset
-    from repro.sim.runtime import make_runtime
-
-    n_dev = jax.local_device_count()
-    cohorts = [8, 16] if quick else [8, 16, 32, 64]
-    nclients = max(cohorts)
-    cfg = FLConfig(num_clients=nclients, num_clusters=1, local_epochs=1,
-                   imbalance_low=0.9, imbalance_high=1.1, seed=0)
-    train, _ = make_image_dataset("mnist", n_train=nclients * 165,
-                                  n_test=64, seed=0)
-    clients = partition_clients(train.y, cfg, seed=0)
-    adapter = cnn_adapter("mnist")
-    params = adapter.init(jax.random.PRNGKey(0))
-    history = np.zeros((nclients,), np.int64)
-    vec = make_runtime(cfg.replace(runtime="vectorized"), adapter,
-                       train.x, train.y, clients)
-    shd = make_runtime(cfg.replace(runtime="sharded"), adapter,
-                       train.x, train.y, clients)
-    out = {"devices": n_dev}
-    for c in cohorts:
-        sel = np.arange(c)
-        t0 = time.time()
-        jax.block_until_ready(shd.train_cohort(params, sel, history))
-        cold_s = time.time() - t0
-        us_v = _t(lambda: vec.train_cohort(params, sel, history),
-                  n=3, warmup=1)
-        us_s = _t(lambda: shd.train_cohort(params, sel, history),
-                  n=3, warmup=0)
-        p_v = vec.train_cohort(params, sel, history)
-        p_s = shd.train_cohort(params, sel, history)
-        diff = max(jax.tree.leaves(jax.tree.map(
-            lambda a, b: float(jnp.max(jnp.abs(a - b))), p_v, p_s)))
-        assert diff < 1e-4, f"sharded drifted from vectorized: {diff}"
-        speedup = us_v / us_s
-        _row(f"cohort_sharded_vec_C{c}", us_v, "devices=1")
-        _row(f"cohort_sharded_shd_C{c}", us_s,
-             f"devices={n_dev} speedup={speedup:.2f}x "
-             f"max_diff={diff:.1e} compile_s={cold_s - us_s / 1e6:.2f}")
-        out[c] = {"vec_us": us_v, "sharded_us": us_s, "speedup": speedup,
-                  "max_param_diff": diff,
-                  "compile_s": max(cold_s - us_s / 1e6, 0.0)}
-    _save("cohort_sharded", out)
-    big = max(c for c in out if isinstance(c, int))
-    _summary("cohort_sharded", devices=n_dev, cohort=big,
-             sharded_us=out[big]["sharded_us"],
-             speedup=out[big]["speedup"])
-
-
-# ----------------------------------------------------------------------
-# micro: end-to-end round pipeline (host-packed vs device-resident)
-# ----------------------------------------------------------------------
-
-def bench_round_pipeline(quick: bool):
-    """Warm end-to-end FL rounds/sec: host-packed ``vectorized`` vs the
-    device-resident ``device`` runtime on the full server loop (stage-2
-    control plane + stage-3 training + async metric buffering), with the
-    per-round cost split into ``host_pack_s`` (numpy gather / index
-    assembly: the ``cohort/pack`` and ``cohort/assemble`` spans) and
-    ``device_s`` (everything else: dispatch + compute + any retraces).
-    The fleet is imbalanced and the scheme picks a fresh random cohort
-    each round, so the vectorized packer keeps meeting new bucket shapes
-    — the realistic regime the capacity-class policy is built for;
-    ``obs.jax_stats`` retrace/hit counters make the "zero retraces after
-    warm-up" claim auditable in the JSON."""
-    from repro import obs
-    from repro.configs.base import FLConfig
-    from repro.core.adapters import cnn_adapter
-    from repro.core.server import FederatedServer
-    from repro.data.partition import partition_clients
-    from repro.data.synthetic import make_image_dataset
-
-    nclients = 24 if quick else 64
-    warm_rounds, timed_rounds = (2, 5) if quick else (3, 8)
-    # the paper's own scheme: eligibility thresholds + per-cluster
-    # auctions make the winner count AND composition shift round to
-    # round, the regime where data-dependent bucket shapes keep the
-    # host-packed path tracing; local_epochs=2 widens the step bands.
-    cfg = FLConfig(num_clients=nclients, num_clusters=4,
-                   select_ratio=10 / nclients if quick else 0.25,
-                   local_epochs=2, scheme="gradient_cluster_auction",
-                   sample_window=20, cluster_resamples=2,
-                   init_energy_mode="normal", eval_every=10 ** 6, seed=0)
-    train, test = make_image_dataset("mnist", n_train=nclients * 130,
-                                     n_test=256, seed=0)
-    adapter = cnn_adapter("mnist")
-    cohort = max(int(round(cfg.select_ratio * nclients)), 1)
-    out = {"cohort": cohort, "clients": nclients,
-           "warm_rounds": warm_rounds, "timed_rounds": timed_rounds}
-    for rt in ("vectorized", "device"):
-        clients = partition_clients(train.y, cfg, seed=0)
-        srv = FederatedServer(cfg.replace(runtime=rt), adapter, train.x,
-                              train.y, clients,
-                              {"x": test.x[:256], "y": test.y[:256]})
-        # warm-up: stage-1 clustering + device-runtime class compiles +
-        # the first rounds' programs — all outside the timed window
-        srv.run(rounds=warm_rounds)
-        jax.block_until_ready(srv.params)
-        stats0 = obs.jax_stats.snapshot()
-        sink = obs.configure(memory=True)
-        t0 = time.time()
-        for t in range(warm_rounds, warm_rounds + timed_rounds):
-            srv._dispatch_round(t, eval_now=False)   # the round pipeline
-        srv._flush_pending()
-        jax.block_until_ready(srv.params)
-        wall = time.time() - t0
-        d = obs.jax_stats.delta(stats0)
-        obs.OBS.reset()
-        pack_s = sum(e["dur_s"] for e in sink.events
-                     if e["kind"] == "span"
-                     and e["name"] in ("cohort/pack", "cohort/assemble"))
-        row = {
-            "rounds_per_s": timed_rounds / wall,
-            "host_pack_s": pack_s,
-            "device_s": wall - pack_s,
-            "retraces_warm": d.get("traces/cohort_engine", 0),
-            "new_shapes_warm": d.get("shape_misses", 0),
-        }
-        out[rt] = row
-        _row(f"round_pipeline_{rt}", wall / timed_rounds * 1e6,
-             f"cohort={cohort} rounds_per_s={row['rounds_per_s']:.2f} "
-             f"host_pack_s={row['host_pack_s']:.3f} "
-             f"retraces_warm={row['retraces_warm']}")
-    out["speedup"] = (out["device"]["rounds_per_s"]
-                      / out["vectorized"]["rounds_per_s"])
-    _row("round_pipeline_speedup", 0.0,
-         f"device_vs_vectorized={out['speedup']:.2f}x")
-    _save("round_pipeline", out)
-    _summary("round_pipeline", cohort=cohort, clients=nclients,
-             warm_rounds_per_s=out["device"]["rounds_per_s"],
-             vectorized_rounds_per_s=out["vectorized"]["rounds_per_s"],
-             retraces_warm=out["device"]["retraces_warm"],
-             speedup=out["speedup"])
 
 
 def bench_fleet_dynamics(quick: bool):
@@ -876,9 +677,6 @@ BENCHES = {
     "kernels": bench_kernels,
     "clustering": bench_clustering,
     "selection": bench_selection,
-    "cohort_engine": bench_cohort_engine,
-    "cohort_sharded": bench_cohort_sharded,
-    "round_pipeline": bench_round_pipeline,
     "fleet_dynamics": bench_fleet_dynamics,
     "robust_agg": bench_robust_agg,
     "self_healing": bench_self_healing,

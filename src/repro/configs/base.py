@@ -393,14 +393,12 @@ class FLConfig:
     fedcs_deadline: float = 1.5
 
     # cohort execution backend (repro.sim): 'sequential' runs the
-    # reference per-client loop; 'vectorized' runs whole cohorts as one
-    # compiled vmap/scan program per size bucket; 'sharded' additionally
-    # maps each bucket's client axis over the cohort mesh's 'data' axis
-    # (shard_map, replicated params, psum FedAvg); 'device' keeps the
-    # whole fleet's data resident on device in static capacity-class
-    # tensors — per-round cohort assembly is an on-device gather and
-    # nothing retraces after warm-up (see ROADMAP.md §Usage, DESIGN.md
-    # §Round pipeline).
+    # reference per-client loop; 'device' keeps the whole fleet's data
+    # resident on device in static capacity-class tensors — per-round
+    # cohort assembly is an on-device gather, nothing retraces after
+    # warm-up, and on a multi-device host each class call's client axis
+    # maps over the cohort mesh's 'data' axis (shard_map, replicated
+    # params, psum FedAvg; see DESIGN.md §Cohort runtimes).
     runtime: str = "sequential"
     # evaluate test accuracy/loss every this many rounds (1 = every
     # round, the paper's cadence; the final round always evaluates,
@@ -408,8 +406,8 @@ class FLConfig:
     # logging boundaries, so together with the device-buffered round
     # metrics this sets the async dispatch depth of FederatedServer.run.
     eval_every: int = 1
-    # devices on the cohort mesh's data axis for runtime='sharded';
-    # 0 = all local devices. Degrades to the 1-device debug mesh.
+    # devices on the cohort mesh's data axis for runtime='device';
+    # 0 = all local devices. One device runs without a mesh.
     cohort_mesh_devices: int = 0
     # client-axis vmap width inside one compiled cohort program; chunks of
     # this width run under lax.map so the per-chunk working set stays

@@ -270,7 +270,7 @@ def cluster_clients(grad_fn: Callable, params, client_data, cfg: FLConfig,
         delta after one epoch of SGD (needs local_steps_fn).
 
     ``precomputed_feats`` (N, D) bypasses the per-client feature loop —
-    the repro.sim vectorized runtime computes the same features as one
+    the repro.sim device runtime computes the same features as one
     batched program; projection and k-means still run here so both paths
     share one code path from raw features onward.
 

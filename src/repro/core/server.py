@@ -12,11 +12,11 @@ Stage 3  (per round): winners run I local epochs (FedAvg local SGD, or
 
 Stage-3 execution is delegated to a pluggable :mod:`repro.sim` cohort
 runtime (``cfg.runtime``): ``sequential`` runs clients one by one (the
-paper's own execution model, kept as the reference oracle), ``vectorized``
-runs the whole cohort as one compiled vmap/scan program per size bucket,
-``sharded`` maps it over the cohort mesh, and ``device`` keeps the whole
-fleet resident on device (repro.sim.fleet) so per-round assembly is an
-on-device gather; the *launch* layer additionally maps cohorts onto mesh
+paper's own execution model, kept as the reference oracle), and
+``device`` keeps the whole fleet resident on device (repro.sim.fleet) so
+per-round assembly is an on-device gather, one compiled vmap/scan
+program per capacity class, mapped over the cohort mesh on a
+multi-device host; the *launch* layer additionally maps cohorts onto mesh
 axes for the TPU-scale path — see repro/launch/train.py.
 
 The round loop is ASYNC: each round's control-plane metrics (and its
@@ -352,7 +352,7 @@ class FederatedServer:
 
         key = self._next_key()
         # the runtime may compute the whole feature pass as one batched
-        # program (vectorized backend); None -> reference per-client loop
+        # program (device backend); None -> reference per-client loop
         feats = self.runtime.cluster_features(self.params, key, feature_kind)
         labels, cent, feats = CL.cluster_clients(
             self.adapter.grad, self.params, data, cfg, key,

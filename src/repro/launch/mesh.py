@@ -34,14 +34,15 @@ def make_debug_mesh():
 
 
 def make_cohort_mesh(num_devices: int = 0):
-    """Mesh for the sharded cohort runtime (repro.sim ``--runtime sharded``):
-    every packed bucket's client axis is shard_map'd over ``data``, params
-    stay replicated, and the weighted FedAvg partial is psum-reduced on-mesh.
+    """Mesh for the device cohort runtime (repro.sim ``--runtime device``):
+    every class call's client axis is shard_map'd over ``data``, params
+    and the class store stay replicated, and the weighted FedAvg partial
+    is psum-reduced on-mesh.
 
     ``num_devices`` caps the data axis (0 = all local devices). With one
-    device this degrades to the 1-device debug mesh, so the sharded runtime
-    runs unchanged (and is tested) on a plain CPU host; CI additionally
-    forces an 8-device CPU mesh via
+    device this degrades to the 1-device debug mesh (the device runtime
+    then skips the mesh unless it is handed one, as tests do); CI
+    additionally forces an 8-device CPU mesh via
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` — that flag must
     be set before first jax init (same caveat as the dry-run's 512).
     """

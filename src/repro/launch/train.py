@@ -23,31 +23,18 @@ Cohort execution backend (``--runtime``, see repro/sim/):
 
   * ``sequential`` (default): the reference oracle — each winner trains
     in its own Python loop of jitted steps.
-  * ``vectorized``: whole-cohort execution — winners are packed into
-    padded, size-bucketed ``(C, steps, bs, ...)`` tensors and their local
-    epochs run as one compiled vmap/scan program per bucket, with the
-    weighted FedAvg aggregation fused in.  Results match ``sequential``
-    up to float reassociation (same shuffles, same batch boundaries).
-    Caveat: clients are bucketed by (batch size, pow2 step band) and
-    padded to the bucket's max step count, so uneven cohorts pay up to
-    ~2x the smallest member's steps within a band; jit retraces per
-    bucket shape (padding rounds the client axis to a multiple of the
-    vmap chunk width and steps to a multiple of 4 to bound the cache).
-  * ``sharded``: the vectorized engine mesh-mapped across the cohort
-    mesh (``--cohort-devices``, default all local devices): each
-    bucket's client axis is shard_map'd over the mesh's ``data`` axis
-    with replicated params and an on-mesh psum FedAvg reduction.  On a
-    1-device host it degrades to the debug mesh (same program); to try
-    a multi-device CPU mesh set
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` BEFORE
-    launching (the flag must precede first jax init — see
-    launch/mesh.py).  Equivalence with ``vectorized`` (and the oracle)
-    is enforced by tests/test_sim.py on both mesh shapes.
   * ``device``: the device-resident fleet pipeline (repro.sim.fleet) —
     all clients' data packed once into static capacity-class device
     tensors at init, per-round cohorts assembled as on-device gathers,
     compile-once shape policy (zero retraces after warm-up), async
-    round loop.  ``--eval-every N`` evaluates test accuracy/loss only
+    round loop.  Results match ``sequential`` up to float
+    reassociation (same shuffles, same batch boundaries).  On a
+    multi-device host each class call's client axis is shard_map'd over
+    the cohort mesh's ``data`` axis (``--cohort-devices``, default all
+    local devices) with an on-mesh psum FedAvg; to try a multi-device
+    CPU mesh set ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+    BEFORE launching (the flag must precede first jax init — see
+    launch/mesh.py).  ``--eval-every N`` evaluates test accuracy/loss only
     every N rounds (skipped rounds log NaN; the final round always
     evaluates) — eval is the deepest per-round host sync, so raising it
     lengthens the async pipeline for every runtime.
@@ -55,8 +42,6 @@ Cohort execution backend (``--runtime``, see repro/sim/):
 Usage:
   PYTHONPATH=src python -m repro.launch.train --mode paper \
       --scheme gradient_cluster_auction --rounds 30
-  PYTHONPATH=src python -m repro.launch.train --mode paper \
-      --runtime vectorized --clients 200 --rounds 30
   PYTHONPATH=src python -m repro.launch.train --mode paper \
       --runtime device --eval-every 5 --rounds 30
   PYTHONPATH=src python -m repro.launch.train --mode transformer \
@@ -308,20 +293,18 @@ def main():
     ap.add_argument("--aggregator", default="fedavg",
                     choices=["fedavg", "fedprox"])
     ap.add_argument("--runtime", default="sequential",
-                    choices=["sequential", "vectorized", "sharded",
-                             "device"],
+                    choices=["sequential", "device"],
                     help="cohort execution backend (repro.sim): "
-                         "'vectorized' runs whole cohorts as one compiled "
-                         "vmap/scan program per size bucket; 'sharded' "
-                         "additionally maps the client axis over the "
-                         "cohort mesh's data axis (shard_map + psum); "
+                         "'sequential' is the per-client reference loop; "
                          "'device' keeps the fleet's data resident on "
                          "device in static capacity classes (compile "
-                         "once, zero per-round host repack)")
+                         "once, zero per-round host repack) and maps "
+                         "the client axis over the cohort mesh's data "
+                         "axis on a multi-device host (shard_map + "
+                         "psum)")
     ap.add_argument("--cohort-devices", type=int, default=0,
                     help="data-axis size of the cohort mesh for "
-                         "--runtime sharded/device (0 = all local "
-                         "devices)")
+                         "--runtime device (0 = all local devices)")
     ap.add_argument("--eval-every", type=int, default=1,
                     help="evaluate test acc/loss every N rounds (skipped "
                          "rounds log NaN; the final round always "

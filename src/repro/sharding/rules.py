@@ -223,32 +223,19 @@ def opt_state_specs(pspecs):
 
 
 # ----------------------------------------------------------------------
-# cohort runtime specs (repro.sim sharded backend)
+# cohort runtime specs (repro.sim device backend on the cohort mesh)
 # ----------------------------------------------------------------------
 # Stage-3 local training is pure data parallelism over the cohort: the
-# packed bucket tensors (xb, yb, step_mask, weights — all with a leading
-# client axis) shard over 'data', the global params are replicated in, and
-# the weighted FedAvg partial sum is psum-reduced across 'data' so the
-# aggregate comes back replicated. The packer pads the client axis to a
-# multiple of the mesh's data size (weight-0 rows), so the shard split is
-# always even.
+# per-invocation winner tensors (all with a leading client axis) shard
+# over 'data', the global params are replicated in, and the weighted
+# FedAvg partial sum is psum-reduced across 'data' so the aggregate comes
+# back replicated. The fleet store pads every tier to a multiple of the
+# mesh's data size (weight-0 rows), so the shard split is always even.
 
 def cohort_param_spec():
     """Global params in / aggregated params out: replicated (P() is a valid
     pytree prefix for the whole param tree)."""
     return P()
-
-
-def cohort_bucket_specs():
-    """(xb, yb, step_mask, weights): client axis over 'data', everything
-    else unsharded."""
-    return (P(D), P(D), P(D), P(D))
-
-
-def cohort_stacked_spec():
-    """Per-client stacked outputs keep their leading client axis on
-    'data'."""
-    return P(D)
 
 
 def fleet_class_specs():

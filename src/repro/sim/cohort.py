@@ -1,36 +1,35 @@
-"""Cohort packing: turn a set of selected clients' index shards into the
-dense, padded minibatch tensors the vectorized engine consumes.
+"""Host-side plans for stage 3 and the stage-1 weight feature pass.
 
 The sequential oracle (``SequentialRuntime.train_client``) iterates, per
 client, ``local_epochs`` shuffled passes of full minibatches of size
 ``bs = min(32, n)`` and *drops the remainder batch* — so every executed
-step sees exactly ``bs`` real samples.  Packing therefore never pads
+step sees exactly ``bs`` real samples.  ``oracle_batch_plan`` is that
+plan; :class:`HostPlanCache` memoizes everything about it except the
+permutation values — a client's batch size, step count and ``x[shard]``
+local data gather depend only on the shard — so per-round work rebuilds
+only the permutations.  The ``device`` runtime's ``FleetStore``
+(repro.sim.fleet) builds its per-round index plans from the cache, and
+the shuffle stream matches the oracle bit-for-bit: the same
+``np.random.default_rng(history * 977 + client_idx)`` seed and the same
+per-epoch ``permutation`` draws.
+
+:func:`pack_feature_pass` packs *all* clients for the clustering
+weight-feature pass (``CohortEngine.weight_features``) into dense,
+padded minibatch tensors, the :class:`CohortBucket`.  Packing never pads
 *inside* a batch; it only pads along
 
   * the **step axis** — clients with fewer steps than the bucket maximum
     get trailing dummy steps whose per-step mask is 0 (the engine turns a
     masked step into the identity), and
   * the **client axis** — each bucket is padded to a multiple of the
-    engine's vmap chunk width with weight-0 dummy clients.
+    engine's vmap chunk width with fully masked dummy clients
+    (client id -1).
 
 Clients with different batch sizes (only those with fewer than 32 local
 samples) cannot share a tensor, and clients with wildly different step
-counts would waste compute on padding, so the cohort is split into
+counts would waste compute on padding, so the fleet is split into
 **buckets** keyed by ``(batch size, power-of-two step band)``: within a
-bucket no client runs more than ~2x the steps of another.  The engine
-runs each bucket separately and the bucket partial aggregates (computed
-against the *global* cohort weights) sum to the full FedAvg update.
-
-The shuffle stream matches the oracle bit-for-bit: the same
-``np.random.default_rng(history * 977 + client_idx)`` seed and the same
-per-epoch ``permutation`` draws.
-
-Everything about a client's plan EXCEPT the permutation values — its
-batch size, step count, and the ``x[shard]`` local data gather — depends
-only on the shard, so :class:`HostPlanCache` memoizes those at runtime
-init and per-round packing rebuilds only the permutations (the old path
-re-derived the plan structure and re-gathered ``x[shard[plan]]`` from the
-full global pool every round).
+bucket no client runs more than ~2x the steps of another.
 """
 from __future__ import annotations
 
@@ -39,25 +38,20 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro import obs
-from repro.configs.base import FLConfig
-
 
 @dataclass
 class CohortBucket:
-    """One homogeneous slice of a cohort (shared batch size).
+    """One homogeneous slice of the packed clients (shared batch size).
 
     Shapes: ``xb (C, S, bs, *feat)``, ``yb (C, S, bs)``, ``step_mask
-    (C, S)`` float32 (1 = real step), ``weights (C,)`` float32 global
-    aggregation weights (over *all* buckets they sum to 1; padded rows are
-    0), ``client_idx (C,)`` int32 global client ids (-1 for padding).
+    (C, S)`` float32 (1 = real step), ``client_idx (C,)`` int32 global
+    client ids (-1 for padding).
     """
 
     client_idx: np.ndarray
     xb: np.ndarray
     yb: np.ndarray
     step_mask: np.ndarray
-    weights: np.ndarray
     batch_size: int
 
     @property
@@ -82,8 +76,8 @@ def _round_up(n: int, mult: int) -> int:
 
 def drop_zero_size_winners(sel_idx: np.ndarray, clients) -> np.ndarray:
     """Winners with no local samples run no steps and carry no FedAvg
-    mass — drop them before packing/weighting (shared by the sequential
-    oracle and the packer so the drop rule can never desynchronize)."""
+    mass — drop them before weighting (the sequential oracle's rule;
+    ``FleetStore.assemble`` applies the same one)."""
     sel_idx = np.asarray(sel_idx)
     if sel_idx.size == 0:
         return sel_idx
@@ -121,9 +115,9 @@ class HostPlanCache:
     size and ``local_epochs``; only the permutation values depend on the
     history-seeded rng.  The cache precomputes the structure (and the
     ``x[shard]``/``y[shard]`` local copies, gathered lazily once per
-    client) so :func:`pack_cohort` rebuilds just the per-epoch
-    permutations per round and gathers minibatches from the small
-    contiguous local arrays instead of the global pool.
+    client) so a round rebuilds just the per-epoch permutations, and the
+    fleet store and the feature pass gather from the small contiguous
+    local arrays instead of the global pool.
 
     :meth:`plan` returns *local* sample indices (into the client's own
     shard), bit-identical to ``shardless`` composition of the oracle:
@@ -174,15 +168,11 @@ class HostPlanCache:
 def _pack_plans(locals_xy: Sequence[Tuple[np.ndarray, np.ndarray]],
                 plans: Sequence[np.ndarray],
                 client_ids: Sequence[int],
-                weights: Sequence[float],
-                chunk_width: int = 4,
-                client_multiple: int = 1) -> List[CohortBucket]:
+                chunk_width: int = 4) -> List[CohortBucket]:
     """Group (plan, local shard) pairs into (batch size, pow2 step band)
     buckets and materialize the padded tensors.  ``locals_xy[m]`` holds
     member m's (x_local, y_local) data and ``plans[m]`` indexes into it
-    (local indices).  ``client_multiple`` forces the padded client axis to
-    a multiple of the mesh's data-axis size so a sharded bucket splits
-    evenly across devices."""
+    (local indices)."""
     by_key: Dict[tuple, List[int]] = {}
     for pos, plan in enumerate(plans):
         key = (plan.shape[1], _next_pow2(max(plan.shape[0], 1)))
@@ -196,11 +186,9 @@ def _pack_plans(locals_xy: Sequence[Tuple[np.ndarray, np.ndarray]],
         # (a 2-client bucket padded to 4 would double its compute)
         c_pad = min(_round_up(len(members), chunk_width),
                     _next_pow2(len(members)))
-        c_pad = _round_up(c_pad, client_multiple)
         xb = np.zeros((c_pad, s_max, bs) + x0.shape[1:], x0.dtype)
         yb = np.zeros((c_pad, s_max, bs), y0.dtype)
         mask = np.zeros((c_pad, s_max), np.float32)
-        w = np.zeros((c_pad,), np.float32)
         cid = np.full((c_pad,), -1, np.int32)
         for row, m in enumerate(members):
             plan = plans[m]
@@ -209,54 +197,10 @@ def _pack_plans(locals_xy: Sequence[Tuple[np.ndarray, np.ndarray]],
             xb[row, :s] = xl[plan]                     # (s, bs, *feat)
             yb[row, :s] = yl[plan]
             mask[row, :s] = 1.0
-            w[row] = weights[m]
             cid[row] = client_ids[m]
         buckets.append(CohortBucket(client_idx=cid, xb=xb, yb=yb,
-                                    step_mask=mask, weights=w,
-                                    batch_size=bs))
-    if obs.OBS.enabled:
-        # padding efficiency counters (emitted at the next flush): how
-        # many bucket programs ran and how many padded client rows they
-        # carried vs real members
-        obs.OBS.counter("pack/buckets", len(buckets))
-        obs.OBS.counter("pack/client_rows",
-                        sum(b.weights.shape[0] for b in buckets))
-        obs.OBS.counter("pack/real_clients", len(plans))
+                                    step_mask=mask, batch_size=bs))
     return buckets
-
-
-def pack_cohort(x: np.ndarray, y: np.ndarray, clients,
-                sel_idx: np.ndarray, history: np.ndarray,
-                cfg: FLConfig, client_multiple: int = 1,
-                cache: Optional[HostPlanCache] = None
-                ) -> List[CohortBucket]:
-    """Pack the round's winners for the engine.
-
-    ``history`` is the pre-round participation count per client (it seeds
-    the oracle's shuffle rng).  Aggregation weights are the oracle's
-    ``p_k = n_k / sum n_k`` over the whole cohort.  Winners with zero
-    local samples contribute no steps and no FedAvg weight, so they are
-    dropped up front (an all-zero cohort packs to [] — the runtimes treat
-    that as "skip aggregation" instead of zeroing the global params).
-
-    ``cache`` carries the memoized plan structure + local data shards
-    across rounds; without one a throwaway cache is built (same result,
-    no reuse).
-    """
-    sel_idx = drop_zero_size_winners(sel_idx, clients)
-    if sel_idx.size == 0:
-        return []
-    if cache is None:
-        cache = HostPlanCache(x, y, clients, cfg.local_epochs)
-    sizes = cache.sizes[sel_idx].astype(np.float64)
-    pk = sizes / sizes.sum()
-
-    locals_xy = [cache.local_data(int(i)) for i in sel_idx]
-    plans = [cache.plan(int(i), int(history[int(i)])) for i in sel_idx]
-    return _pack_plans(locals_xy, plans, [int(i) for i in sel_idx],
-                       [float(p) for p in pk],
-                       chunk_width=cfg.cohort_vmap_width,
-                       client_multiple=client_multiple)
 
 
 def pack_feature_pass(x: np.ndarray, y: np.ndarray, clients,
@@ -264,8 +208,8 @@ def pack_feature_pass(x: np.ndarray, y: np.ndarray, clients,
                       cache: Optional[HostPlanCache] = None
                       ) -> List[CohortBucket]:
     """Pack *all* clients for the clustering weight-feature pass: one
-    in-order epoch per client (no shuffle), unit weights (features are
-    returned per client, not aggregated)."""
+    in-order epoch per client (no shuffle); features are returned per
+    client, not aggregated."""
     if cache is None:
         cache = HostPlanCache(x, y, clients, 1)
     locals_xy, plans = [], []
@@ -274,5 +218,4 @@ def pack_feature_pass(x: np.ndarray, y: np.ndarray, clients,
         plans.append(sequential_batch_plan(int(cache.sizes[i]),
                                            int(cache.bs[i])))
     ids = list(range(len(clients)))
-    return _pack_plans(locals_xy, plans, ids, [1.0] * len(clients),
-                       chunk_width=chunk_width)
+    return _pack_plans(locals_xy, plans, ids, chunk_width=chunk_width)

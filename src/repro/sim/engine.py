@@ -1,61 +1,59 @@
-"""Vectorized cohort engine: one compiled program per bucket shape.
+"""Cohort engine: the compiled programs of the ``device`` runtime.
 
-Three compiled entry points, all ``jax.vmap`` over the client axis with a
+Every program is a ``jax.vmap`` over the client axis with a
 ``jax.lax.scan`` over minibatch steps inside:
 
-When constructed with a ``mesh`` (the sharded runtime), the round-training
-entry point additionally maps each bucket's client axis across the mesh's
-``data`` axis with ``shard_map``: params are replicated in, every device
-runs the same chunked vmap/scan program over its C/ndev slice of the
-bucket tensors, and the weighted FedAvg partial sum is reduced on-mesh
-with a ``psum`` — so the per-round result comes back replicated and only
-the reduction order differs from the single-device program (same
-float-reassociation tolerance class as vectorized-vs-sequential).  The
-packer pads the client axis to a multiple of the data-axis size, so the
-shard split is always even; feature passes stay on the single-device path
-(they feed stage-1 clustering, whose logs must be bit-identical across
-runtimes).
-
-  * :meth:`CohortEngine.train_bucket` — the round's local training: every
-    client runs ``local_epochs`` of SGD (optionally FedProx-proximal)
-    from the shared global params; masked (padding) steps are the
-    identity on both params and optimizer state; the bucket's weighted
-    FedAvg partial sum is fused into the same program.
+  * :meth:`CohortEngine.train_class` — the round's local training for one
+    capacity-class invocation (repro.sim.fleet): every winner runs
+    ``local_epochs`` of SGD (optionally FedProx-proximal) from the shared
+    global params.  The program takes a class's resident ``(P, n_rows,
+    width)`` store of flat sample rows plus tiny per-round int tensors
+    (winner rows + local batch plans) and gathers each step's minibatch
+    *inside* the program (``jnp.take`` by winner row, then a per-step take
+    over the plan, cut to ``prod(feat)`` values and reshaped to ``(bs,
+    *feat)`` for the model; both takes clip, exact for the in-range
+    indices ``FleetStore.assemble`` writes), so per-round host work is
+    index assembly only — no sample crosses host->device after init.
+    Masked (padding) steps are the identity on both params and optimizer
+    state, and the weighted FedAvg partial sum is fused into the same
+    program.
+  * :meth:`CohortEngine.train_class_updates` — its defended-path twin:
+    the same local scans, returning the ``(C_cap, D)`` per-client flat
+    deltas the screened aggregation consumes.
   * :meth:`CohortEngine.weight_features` — the Wang-et-al clustering
-    feature: flattened param delta after one in-order epoch of plain SGD.
+    feature: flattened param delta after one in-order epoch of plain SGD,
+    over the host-packed buckets of ``cohort.pack_feature_pass``.
   * :meth:`CohortEngine.gradient_features` — the paper's clustering
     feature: mean flattened gradient over the T0 sample-window draws.
-  * :meth:`CohortEngine.train_class` — the device-resident twin of
-    ``train_bucket`` for the ``device`` runtime (repro.sim.fleet): instead
-    of consuming host-packed ``(C, S, bs, ...)`` minibatch tensors it
-    takes a capacity class's resident ``(P, n_rows, width)`` store of
-    flat sample rows plus tiny per-round int tensors (winner rows + local
-    batch plans) and gathers each step's minibatch *inside* the compiled
-    program (``jnp.take`` by winner row, then a per-step take over the
-    plan, cut to ``prod(feat)`` values and reshaped to ``(bs, *feat)``
-    for the model; both takes clip, exact for the in-range indices
-    ``FleetStore.assemble`` writes), so
-    per-round host work is index assembly only — no sample ever crosses
-    host->device after init.  Capacity classes are static (derived from
-    the whole fleet at init), so these programs compile once per class;
-    ``obs.jax_stats`` counts traces (``traces/cohort_engine``) and
-    per-shape cache hits/misses to make "zero retraces after warm-up"
-    assertable.
 
-``jax.jit`` retraces per distinct bucket shape ``(C, S, bs)``; the packer
-pads C to a multiple of the vmap chunk width, S to a multiple of 4, and
-band-buckets step counts by power of two to keep that cache small.  The client axis is processed in ``cfg.cohort_vmap_width``-wide
-vmap chunks under an outer ``jax.lax.map``: a full-width vmap multiplies
-the per-op working set by C and thrashes the CPU cache (measured 1.4-2x
-slower than the loop for the paper's CNNs), while narrow chunks keep
-each op cache-resident and still amortize dispatch to one call per
-bucket.  Equivalence with the sequential oracle is exact up to float
+Capacity classes are static (derived from the whole fleet at init), so
+the round programs compile once per (class, tier); ``obs.jax_stats``
+counts traces (``traces/cohort_engine``) and per-shape cache hits/misses
+to make "zero retraces after warm-up" assertable.
+
+When constructed with a ``mesh``, :meth:`CohortEngine.train_class` maps
+each invocation's client axis across the mesh's ``data`` axis with
+``shard_map``: params and the class store are replicated in, every device
+runs the same chunked vmap/scan over its C/ndev slice of the winner
+tensors, and the FedAvg partial is reduced on-mesh with a ``psum`` — only
+the reduction order differs from the single-device program.  The store
+pads each tier to a multiple of the data-axis size, so the shard split is
+always even.  The updates program and the feature passes stay on one
+device (they feed the single-device screen and stage-1 clustering, whose
+labels must be bit-identical to the reference's).
+
+The client axis is processed in ``cfg.cohort_vmap_width``-wide vmap
+chunks under an outer ``jax.lax.map``: a full-width vmap multiplies the
+per-op working set by C and thrashes the CPU cache (measured 1.4-2x
+slower than the loop for the paper's CNNs), while narrow chunks keep each
+op cache-resident and still amortize dispatch to one call per
+invocation.  Equivalence with the sequential oracle is exact up to float
 reassociation (tested in tests/test_sim.py).
 """
 from __future__ import annotations
 
 import math
-from typing import Any, List, Tuple
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -113,16 +111,12 @@ class CohortEngine:
         # per-call shape signatures seen, for obs.jax_stats' hit/miss
         # counters
         self._seen_shapes = set()
-        self._train = self._build_train()      # jitted inside the builder
-        self._train_sharded = (self._build_train_sharded()
-                               if mesh is not None else None)
         self._train_gather = self._build_train_gather()
         self._train_gather_sharded = (self._build_train_gather_sharded()
                                       if mesh is not None else None)
         # per-client flat-delta twins for the defended aggregation path
         # (repro.core.aggregation): built lazily — defense-off runs never
         # construct them, so their jit caches can't perturb anything
-        self._train_updates = None
         self._train_gather_updates = None
         self._weight_feats = jax.jit(self._build_weight_features())
         self._grad_feats = jax.jit(self._build_gradient_features())
@@ -163,9 +157,9 @@ class CohortEngine:
         return apply
 
     def _local_scan(self, params0, opt_init, opt_update, xb, yb, mask,
-                    global_params, proximal: bool, vary=_same):
-        """Scan ``local_step`` over the step axis for one client.
-        ``vary`` maps the initial carry (``_varying`` inside shard_map)."""
+                    global_params, proximal: bool):
+        """Scan ``local_step`` over the step axis for one client, over
+        its packed ``(S, bs, *feat)`` minibatches."""
         upd = self._masked_step(self.adapter.grad, opt_update, proximal,
                                 global_params)
 
@@ -173,7 +167,7 @@ class CohortEngine:
             xs, ys, m = inp
             return upd(*carry, xs, ys, m), None
 
-        (p, _), _ = jax.lax.scan(step, vary((params0, opt_init(params0))),
+        (p, _), _ = jax.lax.scan(step, (params0, opt_init(params0)),
                                  (xb, yb, mask))
         return p
 
@@ -184,8 +178,9 @@ class CohortEngine:
         carries the client's resident (n_rows, width) sample rows and
         gathers each step's (bs,) minibatch by plan indices, cut to its
         first prod(feat) values and reshaped to (bs, *feat) — the padded
-        (S, bs, *feat) tensor of the host-packed path is never
-        materialized.  ``n``: the client's real samples per step, the
+        (S, bs, *feat) tensor that :meth:`_local_scan` consumes is never
+        materialized.  ``vary`` maps the initial carry (``_varying``
+        inside shard_map).  ``n``: the client's real samples per step, the
         first ``n`` of the batch (``ModelAdapter.masked_grad``).  Plan
         indices are in range, so the takes clip (no fill select)."""
         upd = self._masked_step(
@@ -203,48 +198,6 @@ class CohortEngine:
                                  (plan, mask))
         return p
 
-    def _build_train_core(self, vary=_same):
-        """Shared round-training body used by both the single-device and
-        the mesh-mapped builders: per-client local scans (chunked vmap)
-        plus the f32 weighted FedAvg partial.  Returns (stacked, partial)
-        — callers finish the reduction (astype, or psum + astype)."""
-        cfg = self.cfg
-        init, upd = sgd(cfg.lr, momentum=cfg.local_momentum)
-        proximal = cfg.aggregator == "fedprox"
-
-        def core(global_params, xb, yb, mask, weights):
-            obs.jax_stats.note_trace("cohort_engine")
-
-            def one_client(cx, cy, cm):
-                return self._local_scan(global_params, init, upd, cx, cy,
-                                        cm, global_params, proximal, vary)
-
-            stacked = _client_map(one_client, (xb, yb, mask),
-                                  cfg.cohort_vmap_width)
-            partial = jax.tree.map(
-                lambda leaf: jnp.tensordot(weights,
-                                           leaf.astype(jnp.float32),
-                                           axes=1),
-                stacked)
-            return stacked, partial
-
-        return core
-
-    def _build_train(self):
-        core = self._build_train_core()
-
-        def train(global_params, xb, yb, mask, weights,
-                  return_stacked=False):
-            stacked, partial = core(global_params, xb, yb, mask, weights)
-            agg = jax.tree.map(lambda p, s: p.astype(s.dtype),
-                               partial, stacked)
-            # only materialize the (C, ...) per-client trees as a jit
-            # output when asked — the round loop needs just the aggregate
-            # (XLA drops the unfetched stacked outputs otherwise)
-            return (stacked, agg) if return_stacked else agg
-
-        return jax.jit(train, static_argnames="return_stacked")
-
     def _flat_deltas(self, stacked, global_params) -> jnp.ndarray:
         """(C, D) float32 flat param deltas from a stacked (leading-C)
         per-client tree — leaf/concat order is jax.tree.leaves, matching
@@ -255,52 +208,11 @@ class CohortEngine:
             stacked, global_params)
         return jnp.concatenate(jax.tree.leaves(flats), axis=1)
 
-    def _build_train_updates(self):
-        """Per-client flat-delta twin of ``_build_train`` for the
-        defended aggregation path: same local scans, but instead of the
-        fused FedAvg partial it returns the (C, D) update matrix the
-        screened aggregation consumes.  Single-device only — the
-        defended path's screening program is a single-device reduction
-        anyway (see DESIGN.md §Threat model)."""
-        core = self._build_train_core()
-
-        def train(global_params, xb, yb, mask):
-            stacked, _ = core(global_params, xb, yb, mask,
-                              jnp.zeros((xb.shape[0],), jnp.float32))
-            return self._flat_deltas(stacked, global_params)
-
-        return jax.jit(train)
-
-    def _build_train_sharded(self):
-        """The mesh-mapped twin of ``_build_train``: shard_map over the
-        'data' axis, per-device chunked vmap/scan, FedAvg partial reduced
-        with an on-mesh psum.  Only the aggregate is returned (the stacked
-        per-client trees would live sharded on-device; the inspection path
-        stays on the single-device program)."""
-        from repro.sharding.rules import (cohort_bucket_specs,
-                                          cohort_param_spec)
-        core = self._build_train_core(vary=_varying)
-
-        def shard_body(global_params, xb, yb, mask, weights):
-            stacked, partial = core(global_params, xb, yb, mask, weights)
-            # psum the per-device partial across 'data' — weights are
-            # global (they sum to 1 over ALL shards of ALL buckets), so
-            # shard partials just add, same as bucket partials
-            return jax.tree.map(
-                lambda p, s: jax.lax.psum(p, "data").astype(s.dtype),
-                partial, stacked)
-
-        train = jax.shard_map(
-            shard_body, mesh=self.mesh,
-            in_specs=(cohort_param_spec(),) + cohort_bucket_specs(),
-            out_specs=cohort_param_spec())
-        return jax.jit(train)
-
     def _build_train_gather_core(self, vary=_same):
         """Round-training body for the device-resident fleet path: take
         the winners' rows out of the class store (flat sample rows;
-        ``feat`` is a sample's shape), run the same chunked vmap/scan as
-        the bucket path with per-step index gathers, and fuse the f32
+        ``feat`` is a sample's shape), run the chunked vmap/scan with
+        per-step index gathers, and fuse the f32
         weighted FedAvg partial.  ``batch_n (C,)`` holds each row's real
         samples per step.  Winner rows are in range, so the takes clip: no call
         reads the store beyond the rows it gathers.  Returns (stacked,
@@ -426,44 +338,6 @@ class CohortEngine:
         return features
 
     # ------------------------------------------------------------------
-    def train_bucket(self, global_params, bucket: CohortBucket
-                     ) -> Tuple[Any, Any]:
-        """Returns (stacked per-client params with leading C axis,
-        weighted partial aggregate sum_c w_c * params_c).  The stacked
-        trees are for inspection/tests; the round loop uses
-        :meth:`train_cohort`, which skips materializing them."""
-        return self._train(global_params, bucket.xb, bucket.yb,
-                           bucket.step_mask, bucket.weights,
-                           return_stacked=True)
-
-    def train_cohort(self, global_params, buckets: List[CohortBucket]):
-        """Aggregated params over all buckets, or None for an empty
-        cohort.  Weights are global, so bucket partials just add.  With a
-        mesh, each bucket runs mesh-mapped (client axis over 'data') and
-        its partial arrives already psum-reduced and replicated."""
-        step = self._train_sharded if self._train_sharded is not None \
-            else self._train
-        agg = None
-        for b in buckets:
-            self._note_shape(("bucket", b.xb.shape))
-            part = step(global_params, b.xb, b.yb, b.step_mask, b.weights)
-            agg = part if agg is None else jax.tree.map(
-                jnp.add, agg, part)
-        return agg
-
-    def train_bucket_updates(self, global_params, bucket: CohortBucket
-                             ) -> jnp.ndarray:
-        """(C, D) float32 per-client flat deltas for one bucket — the
-        defended aggregation path's stage-3 output (padding rows are
-        all-zero; bucket.client_idx marks them -1).  Compiles per bucket
-        shape like ``train_bucket`` (the defended path adds no *warm*
-        retraces beyond the bucket shapes the plain path already pays)."""
-        if self._train_updates is None:
-            self._train_updates = self._build_train_updates()
-        self._note_shape(("bucket_upd", bucket.xb.shape))
-        return self._train_updates(global_params, bucket.xb, bucket.yb,
-                                   bucket.step_mask)
-
     def train_class(self, global_params, class_x, class_y, rows, plans,
                     step_mask, batch_n, weights, *, feat):
         """One capacity-class invocation of the device-resident round

@@ -1,11 +1,11 @@
 """FleetStore: device-resident fleet data + compile-once capacity classes.
 
-The host-packed runtimes (``vectorized``/``sharded``) rebuild padded
-``(C, S, bs, *feat)`` minibatch tensors on the host every round and pay
-an H2D copy per bucket; worse, the bucket shapes are *data-dependent* —
-``(batch size, pow2 step band)`` over whichever clients won the auction —
-so jit retraces whenever a round's cohort composition shifts.  The
-``device`` runtime replaces both taxes:
+Packing a round's winners into padded ``(C, S, bs, *feat)`` minibatch
+tensors on the host would pay an H2D copy of every sample every round;
+worse, such shapes are *data-dependent* — ``(batch size, pow2 step
+band)`` over whichever clients won the auction — so jit would retrace
+whenever a round's cohort composition shifts.  The ``device`` runtime
+avoids both:
 
 * **Pack once.**  At server init every client's local shard is gathered
   once into a device-resident per-class store of flat sample rows
@@ -58,15 +58,13 @@ so jit retraces whenever a round's cohort composition shifts.  The
   largest-fitting-tier chunking).
 
 Padding waste bound: within a class the pow2 step band keeps any member
-below ~2x the steps of the smallest, same as the bucket path; a member
-whose shard is smaller than the class batch runs its single step per
-epoch over its n samples, the batch's other slots weighing 0 in the loss
-(``ModelAdapter.masked_grad``); the pow2
-tier ladder keeps client-axis padding below 2x the invocation's real
-winner count (exactly the bucket packer's ``next_pow2`` bound; masked
-rows are weight-0 and drop out of the FedAvg sum exactly), at the cost
-of one warm-up compile per (class, tier).  See DESIGN.md §Round
-pipeline.
+below ~2x the steps of the smallest; a member whose shard is smaller
+than the class batch runs its single step per epoch over its n samples,
+the batch's other slots weighing 0 in the loss
+(``ModelAdapter.masked_grad``); the pow2 tier ladder keeps client-axis
+padding below 2x the invocation's real winner count (masked rows are
+weight-0 and drop out of the FedAvg sum exactly), at the cost of one
+warm-up compile per (class, tier).  See DESIGN.md §Round pipeline.
 """
 from __future__ import annotations
 
@@ -233,7 +231,7 @@ class FleetStore:
                  history: np.ndarray) -> List[ClassBatch]:
         """Index tensors for the round's winners.  ``history`` is the
         pre-round host participation mirror (seeds the shuffle rng).
-        Zero-size winners are dropped (same rule as the packers); an
+        Zero-size winners are dropped (the oracle's rule); an
         all-zero cohort assembles to [] — skip aggregation."""
         sel_idx = np.asarray(sel_idx)
         if sel_idx.size:

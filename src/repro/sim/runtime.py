@@ -1,30 +1,21 @@
-"""CohortRuntime: pluggable execution backends for a round's local
-training (selected via ``FLConfig.runtime`` / ``train.py --runtime``).
+"""CohortRuntime: the execution backends for a round's local training
+(selected via ``FLConfig.runtime`` / ``train.py --runtime``).
 
   * ``sequential`` — the reference oracle: one jitted local step, Python
     loops over clients and minibatches (the paper's own execution model).
-  * ``vectorized`` — the repro.sim cohort engine: the whole cohort's
-    local epochs run as one compiled program per size bucket (vmap over
-    clients, scan over steps), with the weighted aggregation fused in.
-  * ``sharded`` — the vectorized engine mesh-mapped over the cohort mesh
-    (launch/mesh.make_cohort_mesh): each bucket's client axis is
-    shard_map'd across the mesh's ``data`` axis with replicated params
-    and an on-mesh psum FedAvg reduction, so a round's local epochs run
-    on every device of the mesh instead of one.  Degrades to the
-    1-device debug mesh (same program, axis size 1) on a plain host.
   * ``device`` — the device-resident fleet pipeline (repro.sim.fleet):
     all clients' data is packed once at init into per-capacity-class
     device tensors; per-round cohort assembly is an on-device gather by
     winner rows driven by tiny host-built int plans, and the compiled
     programs are keyed on *static* fleet-derived capacity classes so
-    nothing retraces after warm-up.  Composes with the cohort mesh: on a
-    multi-device host the per-invocation client axis is shard_map'd over
-    ``data`` with a psum FedAvg, same semantics as ``sharded``.
+    nothing retraces after warm-up.  On a multi-device host the
+    per-invocation client axis is shard_map'd over the cohort mesh's
+    ``data`` axis with a psum FedAvg.
 
-All backends are bit-compatible in *behavior* (same shuffles, same batch
-boundaries, same FedAvg weights); results agree up to float
-reassociation.  The sequential backend stays the ground truth the
-engine backends are tested against (tests/test_sim.py).
+Both backends are bit-compatible in *behavior* (same shuffles, same
+batch boundaries, same FedAvg weights); results agree up to float
+reassociation.  The sequential backend stays the ground truth the device
+backend is tested against (tests/test_sim.py).
 """
 from __future__ import annotations
 
@@ -40,11 +31,11 @@ from repro.core.adapters import ModelAdapter
 from repro.core.aggregation import UpdateBatch, make_flat_delta
 from repro.optim import apply_updates, fedprox_grad, sgd
 from repro.sim.cohort import (HostPlanCache, drop_zero_size_winners,
-                              pack_cohort, pack_feature_pass)
+                              pack_feature_pass)
 from repro.sim.engine import CohortEngine
 from repro.sim.fleet import FleetStore, class_work
 
-RUNTIMES = ("sequential", "vectorized", "sharded", "device")
+RUNTIMES = ("sequential", "device")
 
 
 def tree_weighted_sum(trees: List[Any], weights: np.ndarray):
@@ -184,123 +175,7 @@ class SequentialRuntime:
 
 
 # ----------------------------------------------------------------------
-class VectorizedRuntime(SequentialRuntime):
-    """Cohort engine backend: one compiled program per bucket shape.
-
-    Inherits the oracle's ``train_client`` (single-client calls have no
-    batching to exploit) and overrides the cohort-level entry points.
-    """
-
-    name = "vectorized"
-
-    def __init__(self, cfg, adapter, x, y, clients, mesh=None):
-        super().__init__(cfg, adapter, x, y, clients)
-        self.mesh = mesh
-        self.engine = CohortEngine(adapter, cfg, mesh=mesh)
-        # memoized plan structure + per-client local data shards: packing
-        # rebuilds only the shuffle permutations per round
-        self.plan_cache = HostPlanCache(x, y, clients, cfg.local_epochs)
-
-    def _pack(self, sel_idx, history, client_multiple=1):
-        with obs.span("cohort/pack", winners=int(np.asarray(sel_idx).size)):
-            return pack_cohort(self.x, self.y, self.clients, sel_idx,
-                               history, self.cfg,
-                               client_multiple=client_multiple,
-                               cache=self.plan_cache)
-
-    def train_cohort(self, global_params, sel_idx, history):
-        with obs.span("cohort/train", runtime=self.name,
-                      cohort=int(np.asarray(sel_idx).size)):
-            return self.engine.train_cohort(global_params,
-                                            self._pack(sel_idx, history))
-
-    def train_cohort_updates(self, global_params, sel_idx, history):
-        # the sharded runtime inherits this as-is: per-row deltas feed a
-        # single-device screened reduction, so the updates program always
-        # packs with client_multiple=1 and runs un-mesh-mapped (bucket
-        # shapes differ from the sharded fused path — each traces once)
-        buckets = self._pack(sel_idx, history)
-        if not buckets:
-            return None
-        with obs.span("cohort/train", runtime=self.name,
-                      cohort=int(np.asarray(sel_idx).size), defended=True):
-            deltas = [self.engine.train_bucket_updates(global_params, b)
-                      for b in buckets]
-            return UpdateBatch(
-                deltas=jnp.concatenate(deltas, axis=0),
-                weights=np.concatenate(
-                    [np.asarray(b.weights, np.float32) for b in buckets]),
-                client_idx=np.concatenate(
-                    [np.asarray(b.client_idx, np.int32) for b in buckets]))
-
-    def cluster_features(self, global_params, key, feature_kind):
-        with obs.span("cluster/features", feature=feature_kind,
-                      runtime=self.name):
-            if feature_kind == "weights":
-                # the cache's epochs field is unused by the feature plan
-                # (one in-order epoch); sharing it reuses the local data
-                # gathers
-                buckets = pack_feature_pass(
-                    self.x, self.y, self.clients,
-                    chunk_width=self.cfg.cohort_vmap_width,
-                    cache=self.plan_cache)
-                return self.engine.weight_features(global_params, buckets,
-                                                   len(self.clients))
-            return self.engine.gradient_features(
-                global_params, *self._gather_gradient_windows(key))
-
-    def _gather_gradient_windows(self, key):
-        """Reproduce the reference feature pass's sample-window draws
-        (same fold_in stream as clustering.cluster_clients) and gather
-        them into uniform (N, T0, window, ...) tensors."""
-        from repro.core.clustering import window_indices
-        cfg = self.cfg
-        t0, w = cfg.cluster_resamples, cfg.sample_window
-        n = len(self.clients)
-        xb = np.empty((n, t0, w) + self.x.shape[1:], self.x.dtype)
-        yb = np.empty((n, t0, w), self.y.dtype)
-        for i, c in enumerate(self.clients):
-            shard = np.asarray(c.train_idx)
-            ki = jax.random.fold_in(key, i)
-            for t in range(t0):
-                k = jax.random.fold_in(ki, t)
-                idx = np.asarray(window_indices(k, len(shard), w))
-                g = shard[idx]
-                xb[i, t] = self.x[g]
-                yb[i, t] = self.y[g]
-        return xb, yb
-
-
-# ----------------------------------------------------------------------
-class ShardedRuntime(VectorizedRuntime):
-    """Mesh-mapped cohort engine backend: each bucket's client axis is
-    shard_map'd over the cohort mesh's ``data`` axis (replicated params,
-    per-device chunked vmap/scan, on-mesh psum FedAvg).  The packer pads
-    every bucket's client axis to a multiple of the data-axis size so the
-    shard split is even.  Clustering feature passes inherit the
-    vectorized (single-device) path: they feed stage-1 clustering, whose
-    selection logs must stay bit-identical across runtimes.
-    """
-
-    name = "sharded"
-
-    def __init__(self, cfg, adapter, x, y, clients, mesh=None):
-        if mesh is None:
-            from repro.launch.mesh import make_cohort_mesh
-            mesh = make_cohort_mesh(cfg.cohort_mesh_devices)
-        super().__init__(cfg, adapter, x, y, clients, mesh=mesh)
-
-    def train_cohort(self, global_params, sel_idx, history):
-        with obs.span("cohort/train", runtime=self.name,
-                      cohort=int(np.asarray(sel_idx).size)):
-            buckets = self._pack(
-                sel_idx, history,
-                client_multiple=self.engine.data_axis_size)
-            return self.engine.train_cohort(global_params, buckets)
-
-
-# ----------------------------------------------------------------------
-class DeviceRuntime(VectorizedRuntime):
+class DeviceRuntime(SequentialRuntime):
     """Device-resident fleet backend (repro.sim.fleet): the whole fleet's
     data lives on device in static capacity-class tensors; per-round host
     work shrinks to assembling tiny int index plans (winner rows + the
@@ -308,19 +183,27 @@ class DeviceRuntime(VectorizedRuntime):
     on a fleet-derived class shape, so nothing retraces after
     :meth:`warmup`.  On a multi-device host the per-invocation client
     axis is shard_map'd over the cohort mesh's ``data`` axis (replicated
-    store, psum FedAvg) — same semantics as the sharded runtime.
-    Clustering feature passes inherit the vectorized path (their logs
-    must stay bit-identical across runtimes)."""
+    store, psum FedAvg).
+
+    Inherits the oracle's ``train_client`` (single-client calls have no
+    batching to exploit).  Its stage-1 feature passes run on one device
+    as engine programs over host-gathered tensors: they feed stage-1
+    clustering, whose labels must stay bit-identical to the reference's.
+    """
 
     name = "device"
 
     def __init__(self, cfg, adapter, x, y, clients, mesh=None):
+        super().__init__(cfg, adapter, x, y, clients)
         if mesh is None:
             from repro.launch.mesh import make_cohort_mesh
             m = make_cohort_mesh(cfg.cohort_mesh_devices)
             # the 1-device debug mesh would only add shard_map overhead
             mesh = m if m.shape["data"] > 1 else None
-        super().__init__(cfg, adapter, x, y, clients, mesh=mesh)
+        self.engine = CohortEngine(adapter, cfg, mesh=mesh)
+        # memoized plan structure + per-client local data shards, shared
+        # by the fleet store and the weight feature pass
+        self.plan_cache = HostPlanCache(x, y, clients, cfg.local_epochs)
         self.store = FleetStore(x, y, clients, cfg,
                                 client_multiple=self.engine.data_axis_size,
                                 cache=self.plan_cache)
@@ -416,16 +299,49 @@ class DeviceRuntime(VectorizedRuntime):
                                weights=np.concatenate(ws),
                                client_idx=np.concatenate(ids))
 
+    def cluster_features(self, global_params, key, feature_kind):
+        with obs.span("cluster/features", feature=feature_kind,
+                      runtime=self.name):
+            if feature_kind == "weights":
+                # the cache's epochs field is unused by the feature plan
+                # (one in-order epoch); sharing it reuses the local data
+                # gathers
+                buckets = pack_feature_pass(
+                    self.x, self.y, self.clients,
+                    chunk_width=self.cfg.cohort_vmap_width,
+                    cache=self.plan_cache)
+                return self.engine.weight_features(global_params, buckets,
+                                                   len(self.clients))
+            return self.engine.gradient_features(
+                global_params, *self._gather_gradient_windows(key))
+
+    def _gather_gradient_windows(self, key):
+        """Reproduce the reference feature pass's sample-window draws
+        (same fold_in stream as clustering.cluster_clients) and gather
+        them into uniform (N, T0, window, ...) tensors."""
+        from repro.core.clustering import window_indices
+        cfg = self.cfg
+        t0, w = cfg.cluster_resamples, cfg.sample_window
+        n = len(self.clients)
+        xb = np.empty((n, t0, w) + self.x.shape[1:], self.x.dtype)
+        yb = np.empty((n, t0, w), self.y.dtype)
+        for i, c in enumerate(self.clients):
+            shard = np.asarray(c.train_idx)
+            ki = jax.random.fold_in(key, i)
+            for t in range(t0):
+                k = jax.random.fold_in(ki, t)
+                idx = np.asarray(window_indices(k, len(shard), w))
+                g = shard[idx]
+                xb[i, t] = self.x[g]
+                yb[i, t] = self.y[g]
+        return xb, yb
+
 
 # ----------------------------------------------------------------------
 def make_runtime(cfg: FLConfig, adapter: ModelAdapter, x, y,
                  clients) -> CohortRuntime:
     if cfg.runtime == "sequential":
         return SequentialRuntime(cfg, adapter, x, y, clients)
-    if cfg.runtime == "vectorized":
-        return VectorizedRuntime(cfg, adapter, x, y, clients)
-    if cfg.runtime == "sharded":
-        return ShardedRuntime(cfg, adapter, x, y, clients)
     if cfg.runtime == "device":
         return DeviceRuntime(cfg, adapter, x, y, clients)
     raise ValueError(

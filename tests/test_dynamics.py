@@ -19,7 +19,7 @@ from repro.data.synthetic import make_image_dataset
 from repro.obs.schema import load_jsonl, validate_events
 from repro.sim import dynamics as DYN
 
-RUNTIMES = ("sequential", "vectorized", "sharded", "device")
+RUNTIMES = ("sequential", "device")
 N_CLIENTS = 10
 POOL = 700
 

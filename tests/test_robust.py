@@ -19,7 +19,7 @@ from repro.data.synthetic import make_image_dataset
 from repro.obs.schema import load_jsonl, validate_events
 from repro.sim import dynamics as DYN
 
-RUNTIMES = ("sequential", "vectorized", "sharded", "device")
+RUNTIMES = ("sequential", "device")
 N_CLIENTS = 10
 POOL = 700
 
@@ -246,14 +246,16 @@ def test_screen_capacity_is_pow2_bound():
 # bit-equality boundary: neutral knobs change NOTHING
 # ----------------------------------------------------------------------
 
-@pytest.mark.parametrize("runtime", RUNTIMES)
-def test_defense_knobs_off_bit_identical(runtime, data):
+@pytest.mark.parametrize("runtime,defense_mode", [
+    pytest.param(rt, mode, id=rt if mode == "static" else f"{rt}-{mode}")
+    for mode in ("static", "adaptive") for rt in RUNTIMES])
+def test_defense_knobs_off_bit_identical(runtime, defense_mode, data):
     plain = _server(_cfg(runtime=runtime, rounds=2), data)
     logs_p = plain.run(rounds=2)
     # knobs present but neutral: frac 0 + defense none => defended False
     knobs = _server(_cfg(runtime=runtime, rounds=2, adversary_frac=0.0,
                          attack="scale", attack_scale=9.0,
-                         defense="none"), data)
+                         defense="none", defense_mode=defense_mode), data)
     assert not knobs.defended
     logs_k = knobs.run(rounds=2)
     _assert_trees_equal(plain.params, knobs.params)

@@ -336,7 +336,7 @@ def test_schema_rejects_missing_fairness_scalar():
 # server integration: neutrality across runtimes + scheme metric drain
 # ----------------------------------------------------------------------
 
-RUNTIMES = ("sequential", "vectorized", "sharded", "device")
+RUNTIMES = ("sequential", "device")
 
 
 @pytest.fixture(scope="module")

@@ -1,9 +1,10 @@
 """Cohort execution engine (repro.sim): packing invariants and
-sequential-vs-{vectorized,sharded} equivalence across schemes and uneven
-shards.  In this process the sharded runtime runs on the 1-device debug
-mesh (same shard_map program, data axis size 1); the forced-8-device CPU
-mesh is exercised by the subprocess test at the bottom (XLA_FLAGS must be
-set before first jax init — see launch/mesh.py)."""
+sequential-vs-device equivalence across schemes and uneven shards.  In
+this process the ``device-mesh1`` variant runs the device runtime on the
+1-device debug mesh (the shard_map class program, data axis size 1); the
+forced-8-device CPU mesh is exercised by the subprocess test at the
+bottom (XLA_FLAGS must be set before first jax init — see
+launch/mesh.py)."""
 import functools
 import os
 import subprocess
@@ -19,11 +20,16 @@ from repro.core.adapters import cnn_adapter
 from repro.core.server import FederatedServer
 from repro.data.partition import ClientData, partition_clients
 from repro.data.synthetic import make_image_dataset
-from repro.sim.cohort import (oracle_batch_plan, pack_cohort,
+from repro.launch.mesh import make_cohort_mesh
+from repro.sim.cohort import (oracle_batch_plan, pack_feature_pass,
                               sequential_batch_plan)
-from repro.sim.runtime import make_runtime
+from repro.sim.runtime import DeviceRuntime, make_runtime
 
-ENGINE_RUNTIMES = ("vectorized", "sharded", "device")
+# the device runtime as tested against the oracle: plain; with local SGD
+# momentum (the class program's optimizer state, which momentum 0 leaves
+# empty); and on a 1-device cohort mesh (the shard_map class program,
+# in-process)
+ENGINE_VARIANTS = ("device", "device-momentum", "device-mesh1")
 
 # small pool + strong imbalance: some clients hold fewer than 32 train
 # samples, so packing produces several batch-size buckets and clients
@@ -45,6 +51,22 @@ def data():
     train, test = make_image_dataset("mnist", n_train=POOL, n_test=120,
                                      seed=3)
     return train, test
+
+
+def _variant_cfg(variant, cfg):
+    """``cfg`` for ``variant``'s comparison, the oracle's run included."""
+    if variant == "device-momentum":
+        return cfg.replace(local_momentum=0.9)
+    return cfg
+
+
+def _runtime(variant, cfg, adapter, x, y, clients):
+    if variant == "sequential":
+        return make_runtime(cfg.replace(runtime="sequential"), adapter, x,
+                            y, clients)
+    mesh = make_cohort_mesh(1) if variant == "device-mesh1" else None
+    return DeviceRuntime(cfg.replace(runtime="device"), adapter, x, y,
+                         clients, mesh=mesh)
 
 
 def _server(cfg, data):
@@ -77,34 +99,38 @@ def test_sequential_plan_drops_remainder():
     assert (plan == np.arange(64).reshape(2, 32)).all()
 
 
-def test_pack_cohort_masks_and_weights(data):
+def test_pack_feature_pass_masks_and_rows(data):
+    """The weight feature pass's packing: one in-order epoch per client,
+    every client exactly once; padding rows are fully masked."""
     cfg = _cfg()
     train, _ = data
     clients = partition_clients(train.y, cfg, seed=3)
-    sel = np.arange(N_CLIENTS)
-    hist = np.zeros(N_CLIENTS, np.int64)
-    buckets = pack_cohort(train.x, train.y, clients, sel, hist, cfg)
-    sizes = np.array([c.size for c in clients], np.float64)
-    pk = sizes / sizes.sum()
+    buckets = pack_feature_pass(train.x, train.y, clients,
+                                chunk_width=cfg.cohort_vmap_width)
     seen = {}
     for b in buckets:
         assert b.step_mask.shape == b.xb.shape[:2] == b.yb.shape[:2]
         assert b.xb.shape[2] == b.batch_size
+        assert b.num_steps % 4 == 0       # step axis padded to whole 4s
         for row, cid in enumerate(b.client_idx):
             if cid < 0:                        # padding row: fully masked
                 assert b.step_mask[row].sum() == 0
-                assert b.weights[row] == 0
             else:
-                n = clients[cid].size
+                shard = np.asarray(clients[cid].train_idx)
+                n = shard.size
                 bs = min(32, n)
-                steps = (n - bs) // bs + 1
+                steps = n // bs
                 assert b.batch_size == bs
-                assert b.step_mask[row].sum() == steps * cfg.local_epochs
-                assert b.weights[row] == pytest.approx(pk[cid])
+                assert b.step_mask[row].sum() == steps
+                # natural order, remainder dropped
+                ref = train.x[shard[:steps * bs]].reshape(
+                    (steps, bs) + train.x.shape[1:])
+                assert (b.xb[row, :steps] == ref).all()
+                assert (b.yb[row, :steps]
+                        == train.y[shard[:steps * bs]].reshape(steps, bs)
+                        ).all()
                 seen[int(cid)] = seen.get(int(cid), 0) + 1
-    assert sorted(seen) == list(range(N_CLIENTS))   # each client once
-    total_w = sum(float(b.weights.sum()) for b in buckets)
-    assert total_w == pytest.approx(1.0)
+    assert seen == {c: 1 for c in range(N_CLIENTS)}   # each client once
     assert len(buckets) > 1       # uneven shards -> several buckets
 
 
@@ -185,35 +211,32 @@ def _max_param_diff(p1, p2) -> float:
         lambda a, b: float(jnp.max(jnp.abs(a - b))), p1, p2)))
 
 
-@pytest.mark.parametrize("runtime", ENGINE_RUNTIMES)
+@pytest.mark.parametrize("runtime", ENGINE_VARIANTS)
 def test_train_cohort_matches_oracle(data, runtime):
     """One cohort, every client, nonzero histories: aggregated params of
-    the engine backends agree with the oracle up to float reassociation
-    (sharded runs on the 1-device debug mesh here)."""
-    cfg = _cfg()
+    the device runtime agree with the oracle up to float reassociation."""
+    cfg = _variant_cfg(runtime, _cfg())
     train, _ = data
     clients = partition_clients(train.y, cfg, seed=3)
     adapter = cnn_adapter("mnist")
     params = adapter.init(jax.random.PRNGKey(0))
     hist = np.arange(N_CLIENTS) % 3
     sel = np.arange(N_CLIENTS)
-    seq = make_runtime(cfg.replace(runtime="sequential"), adapter,
-                       train.x, train.y, clients)
-    eng = make_runtime(cfg.replace(runtime=runtime), adapter,
-                       train.x, train.y, clients)
+    seq = _runtime("sequential", cfg, adapter, train.x, train.y, clients)
+    eng = _runtime(runtime, cfg, adapter, train.x, train.y, clients)
     p_seq = seq.train_cohort(params, sel, hist)
     p_eng = eng.train_cohort(params, sel, hist)
     assert _max_param_diff(p_seq, p_eng) < 1e-4
 
 
-@pytest.mark.parametrize("runtime", ENGINE_RUNTIMES)
+@pytest.mark.parametrize("runtime", ENGINE_VARIANTS)
 def test_train_cohort_empty_is_noop(data, runtime):
-    cfg = _cfg(runtime=runtime)
+    cfg = _variant_cfg(runtime, _cfg())
     train, _ = data
     clients = partition_clients(train.y, cfg, seed=3)
     adapter = cnn_adapter("mnist")
     params = adapter.init(jax.random.PRNGKey(0))
-    rt = make_runtime(cfg, adapter, train.x, train.y, clients)
+    rt = _runtime(runtime, cfg, adapter, train.x, train.y, clients)
     assert rt.train_cohort(params, np.array([], np.int64),
                            np.zeros(N_CLIENTS)) is None
 
@@ -224,35 +247,33 @@ def _zero_size_client() -> ClientData:
 
 
 @pytest.mark.parametrize("runtime",
-                         ("sequential",) + ENGINE_RUNTIMES)
+                         ("sequential",) + ENGINE_VARIANTS)
 def test_all_zero_size_cohort_skips_aggregation(data, runtime):
     """Winners with no local samples must not zero the global params: an
     all-zero cohort returns None (the old sequential path multiplied the
     params by an all-zero ``pk`` vector)."""
-    cfg = _cfg(runtime=runtime)
+    cfg = _variant_cfg(runtime, _cfg())
     train, _ = data
     clients = [_zero_size_client() for _ in range(3)]
     adapter = cnn_adapter("mnist")
     params = adapter.init(jax.random.PRNGKey(0))
-    rt = make_runtime(cfg, adapter, train.x, train.y, clients)
+    rt = _runtime(runtime, cfg, adapter, train.x, train.y, clients)
     assert rt.train_cohort(params, np.arange(3), np.zeros(3)) is None
 
 
-@pytest.mark.parametrize("runtime", ENGINE_RUNTIMES)
+@pytest.mark.parametrize("runtime", ENGINE_VARIANTS)
 def test_zero_size_winner_dropped_from_cohort(data, runtime):
     """A zero-size winner among real ones is dropped; the remaining
     cohort matches the oracle on the same reduced selection."""
-    cfg = _cfg()
+    cfg = _variant_cfg(runtime, _cfg())
     train, _ = data
     clients = (list(partition_clients(train.y, cfg, seed=3))[:4]
                + [_zero_size_client()])
     adapter = cnn_adapter("mnist")
     params = adapter.init(jax.random.PRNGKey(0))
     hist = np.zeros(5, np.int64)
-    seq = make_runtime(cfg.replace(runtime="sequential"), adapter,
-                       train.x, train.y, clients)
-    eng = make_runtime(cfg.replace(runtime=runtime), adapter,
-                       train.x, train.y, clients)
+    seq = _runtime("sequential", cfg, adapter, train.x, train.y, clients)
+    eng = _runtime(runtime, cfg, adapter, train.x, train.y, clients)
     p_seq = seq.train_cohort(params, np.arange(5), hist)   # drops idx 4
     p_ref = seq.train_cohort(params, np.arange(4), hist)
     p_eng = eng.train_cohort(params, np.arange(5), hist)
@@ -263,38 +284,40 @@ def test_zero_size_winner_dropped_from_cohort(data, runtime):
 def test_weight_features_missing_client_raises(data):
     """A client id never placed in any bucket must fail loudly (the old
     path died inside jnp.stack with an opaque TypeError)."""
-    cfg = _cfg(runtime="vectorized")
+    cfg = _cfg(runtime="device")
     train, _ = data
     clients = partition_clients(train.y, cfg, seed=3)
     adapter = cnn_adapter("mnist")
     params = adapter.init(jax.random.PRNGKey(0))
     rt = make_runtime(cfg, adapter, train.x, train.y, clients)
-    from repro.sim.cohort import pack_feature_pass
     buckets = pack_feature_pass(train.x, train.y, clients,
-                                chunk_width=cfg.cohort_vmap_width)
+                                chunk_width=cfg.cohort_vmap_width,
+                                cache=rt.plan_cache)
     with pytest.raises(ValueError, match="missing from the packed buckets"):
         # claim one more client than was packed -> id N has no row
         rt.engine.weight_features(params, buckets, len(clients) + 1)
 
 
 @pytest.mark.parametrize("scheme,aggregator,runtime", [
-    ("random", "fedavg", "vectorized"),
-    ("gradient_cluster_auction", "fedavg", "vectorized"),
-    ("gradient_cluster_auction", "fedprox", "vectorized"),
-    ("gradient_cluster_auction", "fedavg", "sharded"),
     ("random", "fedavg", "device"),
     ("gradient_cluster_auction", "fedavg", "device"),
     ("gradient_cluster_auction", "fedprox", "device"),
+    ("weights_cluster_random", "fedavg", "device"),
+    ("gradient_cluster_random", "fedavg", "device"),
+    ("random", "fedprox", "device"),
+    ("gradient_cluster_auction", "fedavg", "device-momentum"),
 ])
 def test_full_loop_equivalence(data, scheme, aggregator, runtime):
-    """Engine runtimes produce identical RoundLog selection/energy fields
-    and matching aggregated params over full rounds (clustering included
-    for the auction scheme — the engine gradient-feature pass must
-    reproduce the reference clustering exactly)."""
+    """The device runtime produces identical RoundLog selection/energy
+    fields and matching aggregated params over full rounds (clustering
+    included for the cluster schemes — the device runtime's gradient and
+    weight feature passes must reproduce the reference clustering
+    exactly)."""
     logs, params = {}, {}
     for rt in ("sequential", runtime):
-        srv = _server(_cfg(scheme=scheme, aggregator=aggregator,
-                           runtime=rt), data)
+        cfg = _cfg(scheme=scheme, aggregator=aggregator,
+                   runtime="sequential" if rt == "sequential" else "device")
+        srv = _server(_variant_cfg(runtime, cfg), data)
         logs[rt] = srv.run()
         params[rt] = srv.params
     for l_seq, l_eng in zip(logs["sequential"], logs[runtime]):
@@ -303,6 +326,27 @@ def test_full_loop_equivalence(data, scheme, aggregator, runtime):
         assert l_seq.mean_bid == l_eng.mean_bid
         assert l_seq.server_reward == l_eng.server_reward
     assert _max_param_diff(params["sequential"], params[runtime]) < 1e-4
+
+
+@pytest.mark.parametrize("name", ("vectorized", "sharded"))
+def test_unknown_runtime_names_the_two(data, name, capsys):
+    """A runtime name outside ``RUNTIMES`` fails at construction and on
+    the command line, naming ``sequential`` and ``device``."""
+    from repro.launch import train as T
+    from repro.sim.runtime import RUNTIMES
+    assert RUNTIMES == ("sequential", "device")
+    train, _ = data
+    cfg = _cfg(runtime=name)
+    clients = partition_clients(train.y, cfg, seed=3)
+    with pytest.raises(ValueError, match="'sequential', 'device'"):
+        make_runtime(cfg, cnn_adapter("mnist"), train.x, train.y, clients)
+    with pytest.raises(SystemExit) as exc, \
+            pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sys, "argv", ["train", "--runtime", name])
+        T.main()
+    assert exc.value.code == 2
+    err = capsys.readouterr().err
+    assert "sequential" in err and "device" in err and name in err
 
 
 # ----------------------------------------------------------------------
@@ -324,38 +368,37 @@ cfg = FLConfig(num_clients=10, num_clusters=3, select_ratio=0.4, rounds=2,
 train, test = make_image_dataset("mnist", n_train=700, n_test=120, seed=3)
 adapter = cnn_adapter("mnist")
 logs, params = {}, {}
-for rt in ("vectorized", "sharded", "device"):
+for rt in ("sequential", "device"):
     clients = partition_clients(train.y, cfg, seed=3)
     srv = FederatedServer(cfg.replace(runtime=rt), adapter, train.x,
                           train.y, clients,
                           {"x": test.x[:64], "y": test.y[:64]})
-    if rt in ("sharded", "device"):
+    if rt == "device":
         assert srv.runtime.engine.data_axis_size == 8, \
             srv.runtime.engine.data_axis_size
-    if rt == "device":
         # every tier must split evenly across the 8-way data axis
         for c in srv.runtime.store.classes:
             assert all(t % 8 == 0 for t in c.tiers), c.tiers
     logs[rt] = srv.run()
     params[rt] = srv.params
-for other in ("sharded", "device"):
-    for l_v, l_s in zip(logs["vectorized"], logs[other]):
-        assert (l_v.selected == l_s.selected).all()
-        assert l_v.energy_std == l_s.energy_std
-        assert l_v.mean_bid == l_s.mean_bid
-    diff = max(jax.tree.leaves(jax.tree.map(
-        lambda a, b: float(jnp.max(jnp.abs(a - b))),
-        params["vectorized"], params[other])))
-    assert diff < 1e-4, (other, diff)
+for l_q, l_d in zip(logs["sequential"], logs["device"]):
+    assert (l_q.selected == l_d.selected).all()
+    assert l_q.energy_std == l_d.energy_std
+    assert l_q.mean_bid == l_d.mean_bid
+diff = max(jax.tree.leaves(jax.tree.map(
+    lambda a, b: float(jnp.max(jnp.abs(a - b))),
+    params["sequential"], params["device"])))
+assert diff < 1e-4, diff
 print("FORCED_MESH_OK", diff)
 """
 
 
-def test_sharded_runtime_on_forced_8_device_mesh():
-    """Full-loop vectorized-vs-sharded equivalence on a real 8-way client
-    split: identical selection logs, params within the reassociation
-    tolerance.  Runs in a subprocess because the device-count flag only
-    takes effect before first jax init (launch/mesh.py caveat)."""
+def test_device_runtime_on_forced_8_device_mesh():
+    """Full-loop sequential-vs-device equivalence with the device
+    runtime's class calls split 8 ways over the cohort mesh: identical
+    selection logs, params within the reassociation tolerance.  Runs in
+    a subprocess because the device-count flag only takes effect before
+    first jax init (launch/mesh.py caveat)."""
     env = dict(os.environ)
     # drop any ambient device-count forcing, then append ours (XLA takes
     # the LAST occurrence, so a developer's exported =4 would win a
